@@ -19,7 +19,7 @@ _CHILD = r"""
 import json, time
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 from repro.core import all_reduce_lacin, all_to_all_lacin
 
 devs = jax.devices(); n = len(devs)
@@ -87,7 +87,9 @@ print(json.dumps(out))
 
 
 def rows():
-    env = dict(os.environ,
+    # Host-device emulation: the child measures HLO and CPU collectives,
+    # never an accelerator this process may already hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
                PYTHONPATH=os.environ.get("PYTHONPATH", "src"))
     res = subprocess.run([sys.executable, "-c", _CHILD], env=env,

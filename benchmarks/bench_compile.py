@@ -3,35 +3,39 @@
 Three measurements, appended to ``benchmarks/BENCH_sim.json`` (run this
 module after ``bench_simulation``, as ``benchmarks/run.py`` does):
 
-* ``compile_cache`` block — the cold/warm/disk split for a bundled
-  spec: the numpy oracle wall time vs (a) a **fresh process** that must
-  compile, (b) a **second fresh process** that restores the executable
-  from the persistent disk cache (`docs/compile_cache.md`), and (c) an
-  in-process seed re-run that reuses the bucketed program outright.
-  The headline number is ``speedup_vs_numpy_with_compile`` measured in
-  the *second* process — the compile tax is paid once per machine, so
-  a fresh process now keeps the compiled engine's win.
+* ``compile_cache`` block — the cold/disk/memory split for a bundled
+  spec, in one process: the numpy oracle wall time vs (a) a **cold**
+  run that must compile, (b) a **disk-restored** run after the
+  in-process LRU is cleared, which deserializes the executable from the
+  persistent cache (`docs/compile_cache.md`) exactly as a fresh process
+  would, and (c) a seed re-run that reuses the bucketed program from
+  memory.  The headline number is ``speedup_vs_numpy_with_compile``,
+  measured on the disk-restored run: the compile tax is paid once per
+  cache directory, so a fresh process keeps the compiled engine's win.
+  Everything runs in this process because an accelerator belongs to one
+  process at a time: a child started after this process touched JAX
+  could not open it.
 * ``xl_scale`` block — a 1040-switch Dragonfly (a=16, p=8, h=8, g=65;
   8320 terminals) pushed through the *cycle* engine (int16 state diet +
   shape bucketing), recording cycles/sec, cold-vs-warm wall time, and
   cost per grid point.  Beyond this scale the ``backend="auto"`` ladder
   still escalates to the flow tier (``bench_flow.py``).
 
-Both subprocesses share one throwaway ``LACIN_CACHE_DIR``, so the block
-also doubles as an end-to-end check that serialized executables survive
-process boundaries (the CI ``cache-smoke`` lane asserts it every push).
+The compile-cache runs use a throwaway ``JAX_COMPILATION_CACHE_DIR``, so
+the block also doubles as an end-to-end check that serialized
+executables restore (the CI ``cache-smoke`` lane checks it across
+processes every push).
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
 from repro import sim, studies
 from repro.core.dragonfly import DragonflyConfig
+from repro.obs.telemetry import cache_stats, clear_caches, disk_cache_entries
 from repro.sim import xengine
 from repro.sim.topology import dragonfly_topology
 
@@ -39,39 +43,23 @@ from .common import quick, row
 
 _ARTIFACT = os.path.join(os.path.dirname(__file__), "BENCH_sim.json")
 
-#: The subprocess payload: run a spec file through the compiled Study
-#: backend and report the study wall time + the engine's own telemetry.
-_CHILD = """
-import json, sys, time
-from repro import studies
 
-t0 = time.perf_counter()
-out = studies.Study(sys.argv[1], backend="jax").run()
-wall = time.perf_counter() - t0
-# One experiment -> one batched program -> one shared timing dict.
-t = out.results[0].provenance["timings"]
-from repro.obs.telemetry import cache_stats, disk_cache_entries
-print(json.dumps({
-    "study_wall_s": round(wall, 4),
-    "compile_s": t["compile_s"],
-    "compile_cached": t["compile_cached"],
-    "points": len(out.results),
-    "cache_entries": len(disk_cache_entries()),
-    "cache_stats": cache_stats(),
-}))
-"""
-
-
-def _child_run(spec_path: str, cache_dir: str) -> dict:
-    env = dict(os.environ, LACIN_CACHE_DIR=cache_dir)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = os.pathsep.join(
-        [src, env.get("PYTHONPATH", "")])
-    proc = subprocess.run([sys.executable, "-c", _CHILD, spec_path],
-                          env=env, capture_output=True, text=True,
-                          timeout=1800, check=True)
-    return json.loads(proc.stdout.splitlines()[-1])
+def _timed_study(exp) -> dict:
+    """Run ``exp`` through the compiled Study backend and report the
+    study wall time + the engine's own telemetry."""
+    t0 = time.perf_counter()
+    out = studies.Study(exp, backend="jax").run()
+    wall = time.perf_counter() - t0
+    # One experiment -> one batched program -> one shared timing dict.
+    t = out.results[0].provenance["timings"]
+    return {
+        "study_wall_s": round(wall, 4),
+        "compile_s": t["compile_s"],
+        "compile_cached": t["compile_cached"],
+        "points": len(out.results),
+        "cache_entries": len(disk_cache_entries()),
+        "cache_stats": cache_stats(),
+    }
 
 
 def _speed_spec() -> studies.ExperimentSpec:
@@ -90,39 +78,31 @@ def _speed_spec() -> studies.ExperimentSpec:
 
 def compile_cache_rows(out: list, blocks: dict) -> None:
     exp = _speed_spec()
-    cache_dir = tempfile.mkdtemp(prefix="lacin-bench-cache-")
-    spec_path = os.path.join(cache_dir, "speed.spec.json")
-    with open(spec_path, "w") as f:
-        f.write(exp.to_json())
 
     t0 = time.perf_counter()
     studies.Study(exp, backend="numpy").run()
     numpy_s = time.perf_counter() - t0
 
-    cold = _child_run(spec_path, cache_dir)
-    second = _child_run(spec_path, cache_dir)
-
-    # In-process tiers, sharing the children's cache dir: this (third)
-    # process restores from disk, and a seed re-run of the restored
-    # program lands in the same shape bucket — nothing compiles at all.
-    saved = os.environ.get("LACIN_CACHE_DIR")
-    os.environ["LACIN_CACHE_DIR"] = cache_dir
+    saved = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="lacin-bench-cache-")
     try:
-        t0 = time.perf_counter()
-        inproc = studies.Study(exp, backend="jax").run()
-        inproc_s = time.perf_counter() - t0
+        clear_caches(memory=True)
+        cold = _timed_study(exp)
+        # Drop the in-process layer: the next acquisition is the one a
+        # fresh process would make, from disk.
+        clear_caches(memory=True)
+        restored = _timed_study(exp)
+        # A seed re-run lands in the same shape bucket: nothing compiles.
         rerun_exp = exp.with_sweep(
             seeds=tuple(s + 100 for s in exp.sweep.seeds))
-        t0 = time.perf_counter()
-        rerun = studies.Study(rerun_exp, backend="jax").run()
-        rerun_s = time.perf_counter() - t0
+        rerun = _timed_study(rerun_exp)
     finally:
         if saved is None:
-            os.environ.pop("LACIN_CACHE_DIR", None)
+            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
         else:
-            os.environ["LACIN_CACHE_DIR"] = saved
-    inproc_t = inproc.results[0].provenance["timings"]
-    rerun_t = rerun.results[0].provenance["timings"]
+            os.environ["JAX_COMPILATION_CACHE_DIR"] = saved
+    rerun_s = rerun["study_wall_s"]
 
     blocks["compile_cache"] = {
         "workload": (f"cin16/uniform/minimal {len(exp.sweep.loads)} loads"
@@ -130,30 +110,27 @@ def compile_cache_rows(out: list, blocks: dict) -> None:
                      f" {exp.sweep.cycles} cycles (bundled spec, 8-seed"
                      f" sweep)"),
         "numpy_s": round(numpy_s, 4),
-        "cold_process": cold,
-        "second_process": second,
-        "third_process_compile_cached": inproc_t["compile_cached"],
-        "third_process_s": round(inproc_s, 4),
-        "seed_rerun_compile_cached": rerun_t["compile_cached"],
-        "seed_rerun_s": round(rerun_s, 4),
+        "cold": cold,
+        "disk_restored": restored,
+        "seed_rerun": rerun,
         "speedup_vs_numpy": round(numpy_s / rerun_s, 2),
         "speedup_vs_numpy_with_compile":
-            round(numpy_s / second["study_wall_s"], 2),
+            round(numpy_s / restored["study_wall_s"], 2),
         "speedup_vs_numpy_cold": round(numpy_s / cold["study_wall_s"], 2),
     }
-    out.append(row("compile/cache/cold_process", cold["study_wall_s"] * 1e6,
+    out.append(row("compile/cache/cold", cold["study_wall_s"] * 1e6,
                    f"compile_cached={cold['compile_cached']} "
                    f"compile={cold['compile_s']}s "
                    f"entries={cold['cache_entries']}"))
-    out.append(row("compile/cache/second_process",
-                   second["study_wall_s"] * 1e6,
-                   f"compile_cached={second['compile_cached']} "
+    out.append(row("compile/cache/disk_restored",
+                   restored["study_wall_s"] * 1e6,
+                   f"compile_cached={restored['compile_cached']} "
                    f"speedup_vs_numpy_with_compile="
-                   f"{numpy_s / second['study_wall_s']:.1f}x "
+                   f"{numpy_s / restored['study_wall_s']:.1f}x "
                    f"(cold={numpy_s / cold['study_wall_s']:.1f}x)"))
     out.append(row("compile/cache/seed_rerun", rerun_s * 1e6,
-                   f"compile_cached={rerun_t['compile_cached']} "
-                   f"compile_s={rerun_t['compile_s']} (bucketed program "
+                   f"compile_cached={rerun['compile_cached']} "
+                   f"compile_s={rerun['compile_s']} (bucketed program "
                    f"reused across seeds; steady speedup="
                    f"{numpy_s / rerun_s:.1f}x)"))
 

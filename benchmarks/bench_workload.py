@@ -48,7 +48,9 @@ print("RESULT " + json.dumps(w.to_dict()))
 
 
 def _extract(devices: int) -> tuple[dict, float]:
-    env = dict(os.environ,
+    # Extraction emulates the mesh on host devices and times no chip; an
+    # accelerator this process may hold stays out of the child's reach.
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH="src")
     t0 = time.perf_counter()

@@ -2,15 +2,12 @@
 
 * Puts ``src/`` on ``sys.path`` so ``import repro`` works without an
   editable install (mirrors the tier-1 ``PYTHONPATH=src`` invocation).
-* Gates the optional ``hypothesis`` dependency: when it is not installed
-  (hermetic CI images), a deterministic fallback sampler is registered so
-  the property tests still run.
 * Points the persistent compile cache (``repro.obs.telemetry``) at a
   fresh per-session temporary directory so tests are hermetic: runs
-  never hit executables a previous session (or the user's real
-  ``~/.cache/lacin-repro``) left behind, and the cold-compile
-  assertions stay meaningful.  Tests that need a specific directory (or
-  a disabled cache) still override ``LACIN_CACHE_DIR`` themselves.
+  never hit executables a previous session left behind, never write
+  into the checkout's ``.jax_cache``, and the cold-compile assertions
+  stay meaningful.  Tests that need a specific directory (or a disabled
+  cache) still override ``JAX_COMPILATION_CACHE_DIR`` themselves.
 """
 import os
 import sys
@@ -20,10 +17,5 @@ _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-os.environ["LACIN_CACHE_DIR"] = tempfile.mkdtemp(prefix="lacin-test-cache-")
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro._compat import hypothesis_fallback
-    hypothesis_fallback.install()
+os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+    prefix="lacin-test-cache-")

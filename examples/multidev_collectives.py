@@ -14,7 +14,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import make_schedule

@@ -39,7 +39,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro._compat import LacinDeprecationWarning
-from repro._compat.jaxapi import axis_size as _bound_axis_size
 
 from .schedule import LacinSchedule, make_schedule
 
@@ -47,7 +46,7 @@ from .schedule import LacinSchedule, make_schedule
 def _resolve_axis_size(axis_name: str, axis_size: int | None) -> int:
     """``axis_size`` if given, else the static size of the bound axis."""
     if axis_size is None:
-        return _bound_axis_size(axis_name)
+        return jax.lax.axis_size(axis_name)
     return int(axis_size)
 
 

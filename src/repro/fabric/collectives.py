@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro._compat.jaxapi import axis_size as _bound_axis_size
 from repro.core.collectives import (all_gather_lacin, all_reduce_lacin,
                                     all_to_all_lacin, reduce_scatter_lacin)
 from repro.core.schedule import LacinSchedule, make_schedule
@@ -60,7 +59,7 @@ def all_to_all_grid(x: jax.Array, axis_names: Sequence[str],
     """
     names = tuple(axis_names)
     if axis_sizes is None:
-        sizes = tuple(_bound_axis_size(a) for a in names)
+        sizes = tuple(jax.lax.axis_size(a) for a in names)
     else:
         sizes = tuple(int(s) for s in axis_sizes)
     insts = ((instance,) * len(names) if isinstance(instance, str)
@@ -93,7 +92,7 @@ def all_reduce_two_level(x: jax.Array, local_axis: str, global_axis: str, *,
     carrying shards of ``1/a`` of the payload — the l-g-l locality the
     paper's Dragonfly composition provides.
     """
-    a = local_size if local_size is not None else _bound_axis_size(local_axis)
+    a = local_size if local_size is not None else jax.lax.axis_size(local_axis)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1)
     pad = (-flat.size) % a
@@ -142,7 +141,7 @@ class LacinCollectives:
                     f"bound mesh has no axis {axis_name!r} (axes: "
                     f"{tuple(self.mesh.axis_names)})")
             return int(self.mesh.shape[axis_name])
-        return _bound_axis_size(axis_name)
+        return jax.lax.axis_size(axis_name)
 
     def axis_instance(self, axis_name: str) -> str:
         return dict(self.axis_instances).get(axis_name, self.instance)

@@ -145,18 +145,17 @@ def maxmin_rates_jax(demand: np.ndarray, link_idx: np.ndarray,
     shape (cached process-wide), bit-compatible semantics with
     :func:`maxmin_rates_numpy` up to float tolerance.
 
-    float64 is scoped with :func:`jax.experimental.enable_x64` rather
-    than the global ``jax_enable_x64`` flag so that the int32-typed
-    cycle engines sharing the process keep their dtypes."""
+    float64 is scoped with ``jax.enable_x64(True)`` rather than the
+    global ``jax_enable_x64`` flag so that the int32-typed cycle engines
+    sharing the process keep their dtypes."""
     import jax
-    import jax.experimental
     key = int(max_iters)
     fn = _JIT_CACHE.get(key)
     if fn is None:
         fn = jax.jit(_jax_core, static_argnums=(4,))
         _JIT_CACHE[key] = fn
     entry_flow = _entry_flow(np.asarray(flow_ptr))
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = fn(np.asarray(demand, np.float64), entry_flow,
                  np.asarray(link_idx), np.asarray(capacity, np.float64),
                  max_iters)
@@ -177,10 +176,7 @@ def maxmin_rates(demand, link_idx, flow_ptr, capacity, *,
         raise ValueError(f"unknown flow solver {solver!r}; "
                          f"expected 'numpy', 'jax' or 'auto'")
     if np.asarray(link_idx).size >= JAX_NNZ_THRESHOLD:
-        try:
-            return maxmin_rates_jax(demand, link_idx, flow_ptr, capacity,
-                                    max_iters=max_iters)
-        except Exception:       # pragma: no cover - jax is an in-repo dep
-            pass
+        return maxmin_rates_jax(demand, link_idx, flow_ptr, capacity,
+                                max_iters=max_iters)
     return maxmin_rates_numpy(demand, link_idx, flow_ptr, capacity,
                               max_iters=max_iters)

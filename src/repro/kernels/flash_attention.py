@@ -29,9 +29,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.jaxapi import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
 
 NEG_INF = -1e30
 
@@ -53,8 +50,8 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (bq, bk)
 
-    qp = qpos_ref[...][:, None]                          # (bq, 1)
-    kp = kpos_ref[...][None, :]                          # (1, bk)
+    qp = qpos_ref[...]                                   # (bq, 1)
+    kp = kpos_ref[...]                                   # (1, bk)
     ok = kp >= 0                                         # padded kv slots < 0
     if causal:
         ok &= kp <= qp
@@ -84,12 +81,11 @@ def _kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_attention(q, k, v, *, q_pos=None, kv_pos=None, causal: bool = True,
                     window: int = 0, block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, T, H, D); k/v: (B, S, KV, D).  Returns (B, T, H, D).
 
-    ``interpret=True`` by default in this repo: the container is CPU-only
-    and Pallas TPU kernels only *execute* on TPU; interpret mode runs the
-    identical kernel body for validation.
+    Compiles for the TPU; ``interpret=True`` runs the identical kernel
+    body in Python on any backend, for validation.
     """
     b, t, h, d = q.shape
     s, kvh = k.shape[1], k.shape[2]
@@ -125,8 +121,8 @@ def flash_attention(q, k, v, *, q_pos=None, kv_pos=None, causal: bool = True,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_q,), lambda bh, qi, ki: (qi,)),
-            pl.BlockSpec((block_k,), lambda bh, qi, ki: (ki,)),
+            pl.BlockSpec((block_q, 1), lambda bh, qi, ki: (qi, 0)),
+            pl.BlockSpec((1, block_k), lambda bh, qi, ki: (0, ki)),
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda bh, qi, ki, g=g, kvh=kvh:
@@ -144,10 +140,11 @@ def flash_attention(q, k, v, *, q_pos=None, kv_pos=None, causal: bool = True,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32), qq, kk, vv)
+    )(q_pos.astype(jnp.int32)[:, None], kv_pos.astype(jnp.int32)[None, :],
+      qq, kk, vv)
 
     out = out[:, :t].reshape(b, h, t, d)
     return jnp.moveaxis(out, 1, 2)

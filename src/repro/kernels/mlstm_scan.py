@@ -24,10 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.jaxapi import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
-
 
 def _kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, h_ref,
             c_scr, n_scr, m_scr, *, chunk: int, dk: int, dv: int,
@@ -43,52 +39,59 @@ def _kernel(q_ref, k_ref, v_ref, li_ref, lf_ref, h_ref,
     q = q_ref[0].astype(jnp.float32) * scale              # (C, dk)
     k = k_ref[0].astype(jnp.float32)                      # (C, dk)
     v = v_ref[0].astype(jnp.float32)                      # (C, dv)
-    li = li_ref[0].astype(jnp.float32)                    # (C,)
-    lf = lf_ref[0].astype(jnp.float32)
-
-    bcum = jnp.cumsum(lf)                                 # inclusive
-    btot = bcum[-1]
+    li = li_ref[0].astype(jnp.float32)                    # (1, C)
+    lf = lf_ref[0].astype(jnp.float32)                    # (1, C)
     m0 = m_scr[0, 0]
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     tri = cols <= rows
 
-    e = bcum[:, None] - bcum[None, :] + li[None, :]       # (C, C)
+    # Mosaic has no cumsum and no 1-D transposes: prefix sums and the
+    # column forms of the gates are masked reductions over (C, C) tiles.
+    def col(row):                                          # (1, C) -> (C, 1)
+        return jnp.sum(jnp.where(rows == cols, row, 0.0), axis=1,
+                       keepdims=True)
+
+    li_c = col(li)
+    bcum_c = jnp.sum(jnp.where(tri, lf, 0.0), axis=1, keepdims=True)
+    bcum_r = jnp.sum(jnp.where(rows <= cols, col(lf), 0.0), axis=0,
+                     keepdims=True)
+    btot = jnp.sum(lf)
+
+    e = bcum_c - bcum_r + li                               # (C, C)
     e = jnp.where(tri, e, -1e30)
-    g = bcum + m0                                          # (C,)
-    m_row = jnp.maximum(jnp.max(e, axis=1), g)
+    g = bcum_c + m0                                        # (C, 1)
+    m_row = jnp.maximum(jnp.max(e, axis=1, keepdims=True), g)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    p = s * jnp.exp(e - m_row[:, None])
+    p = s * jnp.exp(e - m_row)
     p = jnp.where(tri, p, 0.0)
-    c_in = jnp.exp(g - m_row)                              # (C,)
+    c_in = jnp.exp(g - m_row)                              # (C, 1)
     num = (jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
-           + c_in[:, None] * jax.lax.dot_general(
+           + c_in * jax.lax.dot_general(
                q, c_scr[...], (((1,), (0,)), ((), ())),
                preferred_element_type=jnp.float32))
-    dot = (p.sum(axis=1)
-           + c_in * jax.lax.dot_general(
-               q, n_scr[...], (((1,), (0,)), ((), ())),
-               preferred_element_type=jnp.float32)[:, 0])
-    den = jnp.maximum(jnp.abs(dot), jnp.exp(-m_row))[:, None]
+    dot = (p.sum(axis=1, keepdims=True)
+           + c_in * jnp.sum(q * n_scr[...], axis=1, keepdims=True))
+    den = jnp.maximum(jnp.abs(dot), jnp.exp(-m_row))       # (C, 1)
     h_ref[0] = (num / den).astype(h_ref.dtype)
 
     # ---- chunk-end state update -----------------------------------------
-    m_new = jnp.maximum(btot + m0, jnp.max(btot - bcum + li))
-    w = jnp.exp(btot - bcum + li - m_new)                  # (C,)
-    c_scr[...] = (jnp.exp(btot + m0 - m_new) * c_scr[...]
-                  + jax.lax.dot_general(k * w[:, None], v,
+    m_new = jnp.maximum(btot + m0, jnp.max(btot - bcum_r + li))
+    w = jnp.exp(btot - bcum_c + li_c - m_new)              # (C, 1)
+    decay = jnp.exp(btot + m0 - m_new)
+    c_scr[...] = (decay * c_scr[...]
+                  + jax.lax.dot_general(k * w, v,
                                         (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32))
-    n_scr[...] = (jnp.exp(btot + m0 - m_new) * n_scr[...]
-                  + jnp.sum(k * w[:, None], axis=0)[:, None])
+    n_scr[...] = decay * n_scr[...] + jnp.sum(k * w, axis=0, keepdims=True)
     m_scr[...] = jnp.full_like(m_scr, m_new)
 
 
 def mlstm_scan(q, k, v, log_i, log_f, *, chunk: int = 256,
-               interpret: bool = True):
+               interpret: bool = False):
     """q/k/v: (B, T, H, D); log_i/log_f: (B, T, H) -> h: (B, T, H, D).
 
     T must be a multiple of ``chunk`` (pad upstream).  State starts at
@@ -103,8 +106,10 @@ def mlstm_scan(q, k, v, log_i, log_f, *, chunk: int = 256,
         return jnp.moveaxis(x, 2, 1).reshape(b * h, t, *x.shape[3:])
 
     qf, kf, vf = flat(q), flat(k), flat(v)
-    lif = jnp.moveaxis(log_i, 2, 1).reshape(b * h, t)
-    lff = jnp.moveaxis(log_f, 2, 1).reshape(b * h, t)
+    # Gates as (BH, 1, T): a (1, chunk) trailing block is full-height and
+    # lane-aligned, which the TPU lowering requires.
+    lif = jnp.moveaxis(log_i, 2, 1).reshape(b * h, 1, t)
+    lff = jnp.moveaxis(log_f, 2, 1).reshape(b * h, 1, t)
 
     kernel = functools.partial(_kernel, chunk=chunk, dk=d, dv=d,
                                scale=1.0 / np.sqrt(d))
@@ -115,17 +120,17 @@ def mlstm_scan(q, k, v, log_i, log_f, *, chunk: int = 256,
             pl.BlockSpec((1, chunk, d), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, d), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, d), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, ci: (bh, ci)),
-            pl.BlockSpec((1, chunk), lambda bh, ci: (bh, ci)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, ci: (bh, 0, ci)),
         ],
         out_specs=pl.BlockSpec((1, chunk, d), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((d, d), jnp.float32),
-            pltpu.VMEM((d, 1), jnp.float32),
+            pltpu.VMEM((1, d), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, lif, lff)

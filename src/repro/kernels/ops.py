@@ -1,28 +1,22 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU-only container kernels run in ``interpret=True`` mode (the
-kernel body executes in Python); on TPU set ``interpret=False`` (the
-default flips via the REPRO_PALLAS_INTERPRET env var).
+Kernels compile for the TPU by default.  ``interpret=True`` runs the same
+kernel body in Python on any backend; the CPU tests pass it explicitly.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
 
 from .flash_attention import flash_attention as _flash_attention
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
-
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
                                    "interpret"))
 def flash_attention(q, k, v, *, q_pos=None, kv_pos=None, causal: bool = True,
                     window: int = 0, block_q: int = 128, block_k: int = 128,
-                    interpret: bool | None = None):
-    if interpret is None:
-        interpret = _INTERPRET
+                    interpret: bool = False):
     return _flash_attention(q, k, v, q_pos=q_pos, kv_pos=kv_pos,
                             causal=causal, window=window, block_q=block_q,
                             block_k=block_k, interpret=interpret)
@@ -30,8 +24,6 @@ def flash_attention(q, k, v, *, q_pos=None, kv_pos=None, causal: bool = True,
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_scan(q, k, v, log_i, log_f, *, chunk: int = 256,
-               interpret: bool | None = None):
+               interpret: bool = False):
     from .mlstm_scan import mlstm_scan as _mlstm
-    if interpret is None:
-        interpret = _INTERPRET
     return _mlstm(q, k, v, log_i, log_f, chunk=chunk, interpret=interpret)

@@ -174,8 +174,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path,
     t0 = time.time()
     try:
         if mesh_shape:
-            from repro._compat.jaxapi import make_auto_mesh
-            mesh = make_auto_mesh(tuple(mesh_shape), tuple(mesh_axes))
+            from jax.sharding import AxisType
+            mesh = jax.make_mesh(
+                tuple(mesh_shape), tuple(mesh_axes),
+                axis_types=(AxisType.Auto,) * len(mesh_axes))
         else:
             mesh = make_production_mesh(multi_pod=multi_pod)
         cfg, shape, lowered, meta = lower_cell(arch, shape_name, mesh,

@@ -17,8 +17,9 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    from repro._compat.jaxapi import make_auto_mesh
-    return make_auto_mesh(shape, axes)
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 2):

@@ -25,11 +25,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fabric import LacinCollectives
-from repro._compat.jaxapi import shard_map
 from .layers import AxisRules, dense_init
 
 

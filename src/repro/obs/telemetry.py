@@ -16,8 +16,8 @@ of once-per-process:
      distinct shapes must not pin unbounded device executables), and
   2. an on-disk **AOT** layer: compiled executables serialized with
      ``jax.experimental.serialize_executable`` under
-     :func:`cache_dir` (default ``~/.cache/lacin-repro``, override with
-     ``LACIN_CACHE_DIR``, disable with ``LACIN_CACHE_DIR=""``), keyed by
+     :func:`cache_dir` (``JAX_COMPILATION_CACHE_DIR`` when set, an empty
+     value disabling it; otherwise ``<checkout>/.jax_cache``), keyed by
      a content digest of the program identity (see :func:`_disk_key`).
      Entries are versioned, written atomically (concurrent writers are
      safe — last writer wins and both blobs are valid), and loads are
@@ -98,20 +98,23 @@ def reset_cache_stats() -> None:
         _STATS[k] = 0
 
 
+#: The checkout holding the ``repro`` package (``<checkout>/src/repro``).
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
 def cache_dir() -> Path | None:
     """The persistent compile-cache directory, or ``None`` when disabled.
 
-    ``LACIN_CACHE_DIR`` overrides the default
-    ``$XDG_CACHE_HOME/lacin-repro`` (``~/.cache/lacin-repro``); the
-    empty string disables the disk layer entirely (the memory cache
-    still applies).  The directory is created lazily on first write.
+    ``JAX_COMPILATION_CACHE_DIR`` places it, so the executables share the
+    directory JAX's own persistent cache uses; the empty string disables
+    the disk layer entirely (the memory cache still applies).  Unset, it
+    is the fixed ``<checkout>/.jax_cache``.  The directory is created
+    lazily on first write.
     """
-    env = os.environ.get("LACIN_CACHE_DIR")
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env is not None:
         return Path(env) if env else None
-    xdg = os.environ.get("XDG_CACHE_HOME")
-    base = Path(xdg) if xdg else Path.home() / ".cache"
-    return base / "lacin-repro"
+    return _CHECKOUT / ".jax_cache"
 
 
 def disk_cache_entries() -> list[Path]:
@@ -195,21 +198,18 @@ def _source_digest() -> str:
 
 def _env_header() -> dict:
     import jax
-    try:
-        import jaxlib
-        jaxlib_ver = jaxlib.__version__
-    except Exception:  # pragma: no cover - jaxlib ships with jax
-        jaxlib_ver = None
+    import jaxlib
     return {"format": CACHE_FORMAT, "jax": jax.__version__,
-            "jaxlib": jaxlib_ver, "backend": jax.default_backend(),
+            "jaxlib": jaxlib.__version__, "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
             "src": _source_digest()}
 
 
 def _disk_key(fn, static_arg, aval_key, key_extra) -> str:
     """Content digest naming a disk entry.  Anatomy (all parts must
     match for a hit): cache format version, jax + jaxlib versions, XLA
-    backend, a digest of the ``repro`` source tree (so editing the
-    engine invalidates executables it compiled — see
+    backend and device kind, a digest of the ``repro`` source tree (so
+    editing the engine invalidates executables it compiled — see
     :func:`_source_digest`), the wrapped function's qualified name, the
     static argument's ``repr`` (for xengine this is the :class:`XSpec` —
     every field of the compiled program's shape), the argument avals
@@ -371,19 +371,21 @@ def timed_compiled(fn, static_arg, *args, grid_points: int = 1,
 def provenance(timing: dict | None = None, *, backend: str | None = None,
                spec_digest: str | None = None) -> dict:
     """The environment/provenance block persisted with results and
-    benchmark artifacts: where and with what a number was produced."""
+    benchmark artifacts: where and with what a number was produced,
+    down to the device (``device`` is JAX's platform, ``device_kind``
+    and device count)."""
+    import jax
+    dev = jax.devices()[0]
     out = {
         "host": platform.node(),
         "platform": platform.platform(),
         "python": platform.python_version(),
         "numpy": np.__version__,
         "cpu_count": os.cpu_count(),
+        "jax": jax.__version__,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
     }
-    try:
-        import jax
-        out["jax"] = jax.__version__
-    except Exception:       # pragma: no cover - jax is a hard dep in-repo
-        out["jax"] = None
     if backend is not None:
         out["backend"] = backend
     if spec_digest:

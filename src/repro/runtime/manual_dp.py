@@ -15,9 +15,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro._compat.jaxapi import shard_map
 from repro.fabric import LacinCollectives
 from repro.models import ModelConfig
 from repro.models.layers import AxisRules

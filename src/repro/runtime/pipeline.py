@@ -24,10 +24,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro._compat.jaxapi import shard_map
 from repro.models import ModelConfig
 from repro.models.layers import AxisRules
 from repro.models import layers as L

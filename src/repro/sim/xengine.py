@@ -69,7 +69,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .._compat import jaxapi
 from ..obs.telemetry import timed_compiled
 from ..obs.trace import Trace, TraceConfig, derive_backlog
 from .engine import _DRAIN_SLACK
@@ -730,9 +729,9 @@ def _sharded_runner(spec: XSpec, ndev: int, pkt_keys: tuple):
     shard outputs gain a leading device axis and reassemble on the host
     (see :func:`sweep`).  Donating the packet/warmup operands lets XLA
     reuse their buffers for the (much larger) state."""
-    from jax.sharding import PartitionSpec
+    from jax.sharding import AxisType, PartitionSpec
 
-    mesh = jaxapi.make_auto_mesh((ndev,), ("copies",))
+    mesh = jax.make_mesh((ndev,), ("copies",), axis_types=(AxisType.Auto,))
     rep, shard = PartitionSpec(), PartitionSpec("copies")
     pkt_specs = {k: (rep if k in ("src", "dst", "gen") else shard)
                  for k in pkt_keys}
@@ -741,7 +740,7 @@ def _sharded_runner(spec: XSpec, ndev: int, pkt_keys: tuple):
         out = _run_loop(spec, tables, pkt, key, warmup)
         return jax.tree_util.tree_map(lambda a: a[None], out)
 
-    return jax.jit(jaxapi.shard_map(
+    return jax.jit(jax.shard_map(
         run, mesh=mesh, in_specs=(rep, pkt_specs, rep, shard),
         out_specs=shard, check_vma=False), donate_argnums=(1, 3))
 

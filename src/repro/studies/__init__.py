@@ -43,13 +43,12 @@ import os
 from .spec import (ExperimentSpec, FabricSpec, RoutingSpec, SweepSpec,
                    TrafficSpec, dump_specs, load_specs)
 from .store import JsonlStore, Result
-from .runner import (BACKENDS, FLOW_AUTO_SWITCHES, Study, StudyResult,
-                     jax_available)
+from .runner import BACKENDS, FLOW_AUTO_SWITCHES, Study, StudyResult
 
 __all__ = [
     "ExperimentSpec", "FabricSpec", "TrafficSpec", "RoutingSpec",
     "SweepSpec", "load_specs", "dump_specs",
-    "Result", "JsonlStore", "Study", "StudyResult", "jax_available",
+    "Result", "JsonlStore", "Study", "StudyResult",
     "BACKENDS", "FLOW_AUTO_SWITCHES",
     "bundled_specs", "bundled_spec_path", "resolve_spec_source",
 ]

@@ -295,7 +295,8 @@ def cmd_cache(args) -> int:
                                      disk_cache_entries)
     cdir = cache_dir()
     if cdir is None:
-        print("compile cache: disabled (LACIN_CACHE_DIR is set but empty)")
+        print("compile cache: disabled "
+              "(JAX_COMPILATION_CACHE_DIR is set but empty)")
         return 0
     entries = disk_cache_entries()
     if args.clear:

@@ -5,8 +5,9 @@ One :class:`Study` executes the (load x seed) grid of one or more
 
 * **Backend auto-selection.**  ``backend=None``/"auto" compiles each
   experiment's grid into a single batched :func:`repro.sim.xengine.sweep`
-  program when JAX is importable, and falls back to looping the numpy
-  oracle (:func:`repro.sim.engine.simulate`) otherwise.  Same-shape
+  program, escalating to the flow tier at :data:`FLOW_AUTO_SWITCHES`
+  switches; ``backend="numpy"`` loops the oracle
+  (:func:`repro.sim.engine.simulate`) instead.  Same-shape
   programs across experiments (same topology size, policy, horizon,
   grid size) additionally share one compilation through the jit cache.
 * **Streaming persistence.**  Each finished grid point becomes a
@@ -30,8 +31,7 @@ from typing import Sequence
 from .spec import ExperimentSpec, load_specs
 from .store import JsonlStore, Result
 
-__all__ = ["BACKENDS", "FLOW_AUTO_SWITCHES", "Study", "StudyResult",
-           "jax_available"]
+__all__ = ["BACKENDS", "FLOW_AUTO_SWITCHES", "Study", "StudyResult"]
 
 #: The valid ``backend=`` values, in the order the CLI offers them —
 #: the single source of truth shared by :func:`_select_backend` and
@@ -45,22 +45,12 @@ BACKENDS = ("auto", "jax", "numpy", "flow")
 FLOW_AUTO_SWITCHES = 1024
 
 
-def jax_available() -> bool:
-    try:
-        import jax  # noqa: F401
-        return True
-    except Exception:  # pragma: no cover - jax is a hard dep in-repo
-        return False
-
-
 def _select_backend(backend: str | None, *,
                     num_switches: int | None = None,
                     experiment: "ExperimentSpec | None" = None) -> str:
     if backend in (None, "auto"):
-        if num_switches is not None and num_switches >= FLOW_AUTO_SWITCHES:
-            choice = "flow"
-        else:
-            choice = "jax" if jax_available() else "numpy"
+        big = num_switches is not None and num_switches >= FLOW_AUTO_SWITCHES
+        choice = "flow" if big else "jax"
     elif backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; "
                          f"expected one of {BACKENDS}")

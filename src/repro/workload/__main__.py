@@ -67,7 +67,9 @@ def cmd_extract(args) -> int:
         "step_kw": ({"dp": args.dp} if args.step == "moe" and args.dp > 1
                     else {}),
     }
-    env = dict(os.environ)
+    # The child emulates the mesh on host devices (HLO extraction only), so
+    # it never needs, or contends for, an accelerator.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
                         f"platform_device_count={args.devices}").strip()
     env["PYTHONPATH"] = os.pathsep.join(

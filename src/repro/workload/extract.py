@@ -151,7 +151,7 @@ def moe_step_hlo(num_devices: int, *, dp: int = 1, d_model: int = 32,
     """
     import jax
     import jax.numpy as jnp
-    from repro._compat.jaxapi import make_auto_mesh, set_mesh
+    from jax.sharding import AxisType
     from repro.models.config import ModelConfig
     from repro.models.layers import AxisRules
     from repro.models.moe import apply_moe, init_moe
@@ -163,11 +163,12 @@ def moe_step_hlo(num_devices: int, *, dp: int = 1, d_model: int = 32,
         num_heads=4, num_kv_heads=2, d_ff=d_ff, vocab_size=64,
         num_experts=num_experts if num_experts is not None else ep,
         top_k=2, expert_pad_to=1, capacity_factor=2.0)
-    mesh = make_auto_mesh((dp, ep), ("data", "model"))
+    mesh = jax.make_mesh((dp, ep), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = AxisRules(dp=("data",), tp="model", mesh=mesh)
     p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (batch, seq, d_model))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return compiled_hlo(lambda p_, x_: apply_moe(p_, x_, cfg, rules)[0],
                             p, x)
 
@@ -187,7 +188,7 @@ def dp_step_hlo(num_devices: int, *, d_model: int = 32, num_layers: int = 1,
     ``collective-permute`` chains in the sequence."""
     import jax
     import jax.numpy as jnp
-    from repro._compat.jaxapi import make_auto_mesh
+    from jax.sharding import AxisType
     from repro.optim import OptConfig
     from repro.runtime.manual_dp import make_manual_dp_train_step
     from repro.runtime.trainer import init_train_state
@@ -196,7 +197,8 @@ def dp_step_hlo(num_devices: int, *, d_model: int = 32, num_layers: int = 1,
                          f"num_devices={num_devices}")
     cfg = _tiny_dense_cfg("extract-dp", num_layers=num_layers,
                           d_model=d_model)
-    mesh = make_auto_mesh((num_devices,), ("data",))
+    mesh = jax.make_mesh((num_devices,), ("data",),
+                         axis_types=(AxisType.Auto,))
     step = make_manual_dp_train_step(cfg, mesh, OptConfig(),
                                      compress=compress)
     state = init_train_state(jax.random.PRNGKey(0), cfg)
@@ -214,13 +216,14 @@ def pipeline_step_hlo(num_devices: int, *, d_model: int = 32,
     neighbour ``source_target_pairs``."""
     import jax
     import jax.numpy as jnp
-    from repro._compat.jaxapi import make_auto_mesh
+    from jax.sharding import AxisType
     from repro.models.transformer import init_params
     from repro.runtime.pipeline import make_pipeline_loss_fn
     cfg = _tiny_dense_cfg("extract-pipe",
                           num_layers=num_devices * layers_per_stage,
                           d_model=d_model)
-    mesh = make_auto_mesh((num_devices,), ("pipe",))
+    mesh = jax.make_mesh((num_devices,), ("pipe",),
+                         axis_types=(AxisType.Auto,))
     loss_fn = make_pipeline_loss_fn(cfg, mesh, n_micro=n_micro)
     params = init_params(jax.random.PRNGKey(0), cfg)
     batch_d = {"tokens": jnp.zeros((batch, seq), jnp.int32),

@@ -11,7 +11,7 @@ _CHILD = r"""
 import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 from repro.core import (all_to_all_lacin, all_gather_lacin,
                         reduce_scatter_lacin, all_reduce_lacin)
 

@@ -5,8 +5,9 @@ is truncated, bit-flipped, version-mismatched, or simply not a cache
 entry at all is skipped (and evicted) with a silent fallback to
 recompilation.  Writers are atomic (``os.replace``), so concurrent
 processes racing on one key both leave valid blobs.  The in-process
-layer is a bounded LRU.  ``LACIN_CACHE_DIR=""`` disables the disk layer
-entirely.  Counters (:func:`cache_stats`) make all of it observable.
+layer is a bounded LRU.  ``JAX_COMPILATION_CACHE_DIR=""`` disables the
+disk layer entirely.  Counters (:func:`cache_stats`) make all of it
+observable.
 """
 import os
 import pickle
@@ -15,6 +16,7 @@ import sys
 import textwrap
 import threading
 from functools import partial
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -30,7 +32,7 @@ from repro.obs.telemetry import (CACHE_FORMAT, cache_dir, cache_stats,
 def cache(tmp_path, monkeypatch):
     """A fresh, isolated cache: empty tmp dir, empty memory LRU, zeroed
     counters."""
-    monkeypatch.setenv("LACIN_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     clear_caches(memory=True)
     reset_cache_stats()
     yield tmp_path
@@ -117,7 +119,7 @@ def test_source_edit_invalidates_disk_entries(cache, monkeypatch):
 
 
 def test_empty_cache_dir_disables_disk_layer(cache, monkeypatch):
-    monkeypatch.setenv("LACIN_CACHE_DIR", "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
     assert cache_dir() is None
     poly, x = _program()
     _, t1 = timed_compiled(poly, 3, x)
@@ -130,11 +132,13 @@ def test_empty_cache_dir_disables_disk_layer(cache, monkeypatch):
 
 
 def test_cache_dir_env_resolution(monkeypatch, tmp_path):
-    monkeypatch.setenv("LACIN_CACHE_DIR", str(tmp_path / "override"))
-    assert cache_dir() == tmp_path / "override"
-    monkeypatch.delenv("LACIN_CACHE_DIR")
-    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path / "xdg"))
-    assert cache_dir() == tmp_path / "xdg" / "lacin-repro"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "over"))
+    assert cache_dir() == tmp_path / "over"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    # Unset: a fixed directory in the checkout, whatever HOME says.
+    monkeypatch.setenv("HOME", str(tmp_path / "home"))
+    checkout = Path(list(repro.__path__)[0]).resolve().parents[1]
+    assert cache_dir() == checkout / ".jax_cache"
 
 
 def test_memory_lru_is_bounded(cache, monkeypatch):
@@ -230,7 +234,7 @@ def test_second_process_restores_from_disk(cache):
         print("CACHED:", t["compile_cached"])
     """)
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
-    env = dict(os.environ, LACIN_CACHE_DIR=str(cache),
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
                PYTHONPATH=os.pathsep.join(
                    [src, os.environ.get("PYTHONPATH", "")]))
     runs = [subprocess.run([sys.executable, "-c", script], env=env,

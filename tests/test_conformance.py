@@ -187,7 +187,7 @@ def test_bucketing_invariance_property(points, cycles):
 
 def test_disk_restored_executable_bit_identical(tmp_path, monkeypatch):
     from repro.obs import telemetry
-    monkeypatch.setenv("LACIN_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     telemetry.clear_caches(memory=True)
     fresh = _sweep()
     assert fresh[0][0].timing["compile_cached"] is False
@@ -248,7 +248,7 @@ def test_sharded_program_bit_identical(tmp_path):
     src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
-               LACIN_CACHE_DIR=str(tmp_path),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
                PYTHONPATH=os.pathsep.join(
                    [src, os.environ.get("PYTHONPATH", "")]))
     proc = subprocess.run([sys.executable, "-c", _SHARD_SCRIPT], env=env,

@@ -53,7 +53,7 @@ with tempfile.TemporaryDirectory() as td:
 from repro.optim import OptConfig
 from repro.runtime.manual_dp import (lacin_grad_allreduce,
                                      make_manual_dp_train_step)
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 
 mesh = Mesh(np.array(devs), ("data",))
 rng = np.random.default_rng(0)

@@ -221,7 +221,7 @@ def test_collective_shims_warn():
         assert tree_all_reduce_lacin({}, "x", axis_size=4) == {}
 
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro._compat.jaxapi import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 
     def body(x):

@@ -25,7 +25,7 @@ import json
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 from repro.core import DragonflyConfig
 from repro.fabric import LacinCollectives, make_fabric
 

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracle,
-executed in interpret mode on CPU."""
+executed in interpret mode (``interpret=True``) on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,7 +34,8 @@ def test_flash_vs_ref_causal(dtype, b, t, s, h, kvh, d):
     q, k, v = make_qkv(0, b, t, s, h, kvh, d, dtype)
     # offset q positions so q attends to the cache prefix (s >= t)
     q_pos = jnp.arange(s - t, s, dtype=jnp.int32)
-    out = flash_attention(q, k, v, q_pos=q_pos, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, q_pos=q_pos, block_q=64, block_k=64,
+                          interpret=True)
     ref = reference_attention(q, k, v, q_pos=q_pos)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -44,7 +45,8 @@ def test_flash_vs_ref_causal(dtype, b, t, s, h, kvh, d):
 @pytest.mark.parametrize("window", [1, 7, 64, 1000])
 def test_flash_sliding_window(window):
     q, k, v = make_qkv(1, 2, 128, 128, 4, 2, 64, jnp.float32)
-    out = flash_attention(q, k, v, window=window, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, window=window, block_q=64, block_k=64,
+                          interpret=True)
     ref = reference_attention(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -52,7 +54,8 @@ def test_flash_sliding_window(window):
 
 def test_flash_noncausal():
     q, k, v = make_qkv(2, 1, 64, 96, 2, 2, 64, jnp.float32)
-    out = flash_attention(q, k, v, causal=False, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, causal=False, block_q=32, block_k=32,
+                          interpret=True)
     ref = reference_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -62,7 +65,8 @@ def test_flash_decode_shape():
     """T=1 decode against a long cache."""
     q, k, v = make_qkv(3, 4, 1, 512, 8, 2, 64, jnp.float32)
     q_pos = jnp.asarray([511], jnp.int32)
-    out = flash_attention(q, k, v, q_pos=q_pos, block_q=8, block_k=128)
+    out = flash_attention(q, k, v, q_pos=q_pos, block_q=8, block_k=128,
+                          interpret=True)
     ref = reference_attention(q, k, v, q_pos=q_pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -72,7 +76,7 @@ def test_flash_fully_masked_rows_are_zero():
     """Queries with position before every key -> all-masked -> zeros."""
     q, k, v = make_qkv(4, 1, 16, 32, 2, 2, 32, jnp.float32)
     q_pos = jnp.full((16,), -5, jnp.int32)    # before all kv positions
-    out = flash_attention(q, k, v, q_pos=q_pos)
+    out = flash_attention(q, k, v, q_pos=q_pos, interpret=True)
     assert np.allclose(np.asarray(out), 0.0)
 
 
@@ -87,7 +91,8 @@ def test_flash_fully_masked_rows_are_zero():
 def test_flash_property_sweep(t, h, g, d, window):
     kvh = h
     q, k, v = make_qkv(t * h + d, 1, t, t, h * g, kvh, d, jnp.float32)
-    out = flash_attention(q, k, v, window=window, block_q=32, block_k=32)
+    out = flash_attention(q, k, v, window=window, block_q=32, block_k=32,
+                          interpret=True)
     ref = reference_attention(q, k, v, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)
@@ -126,7 +131,7 @@ def test_mlstm_pallas_kernel_vs_oracle(b, t, h, d, chunk):
     from repro.kernels.ops import mlstm_scan
     from repro.kernels.ref import reference_mlstm
     q, k, v, li, lf = _mlstm_inputs(b * t + d, b, t, h, d)
-    out = mlstm_scan(q, k, v, li, lf, chunk=chunk)
+    out = mlstm_scan(q, k, v, li, lf, chunk=chunk, interpret=True)
     ref, _ = reference_mlstm(q, k, v, li, lf)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=5e-4, atol=5e-5)
@@ -136,7 +141,7 @@ def test_mlstm_pallas_kernel_bf16():
     from repro.kernels.ops import mlstm_scan
     from repro.kernels.ref import reference_mlstm
     q, k, v, li, lf = _mlstm_inputs(11, 1, 64, 2, 32, jnp.bfloat16)
-    out = mlstm_scan(q, k, v, li, lf, chunk=32)
+    out = mlstm_scan(q, k, v, li, lf, chunk=32, interpret=True)
     ref, _ = reference_mlstm(q, k, v, li, lf)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),

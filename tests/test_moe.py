@@ -69,15 +69,16 @@ cfg = ModelConfig(name="tiny-moe", family="moe", num_layers=1, d_model=32,
                   num_heads=4, num_kv_heads=2, d_ff=16, vocab_size=64,
                   num_experts=8, top_k=2, expert_pad_to=1,
                   capacity_factor=8.0)  # big cf: nothing dropped -> exact
-from repro._compat.jaxapi import make_auto_mesh, set_mesh
-mesh = make_auto_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = AxisRules(dp=("data",), tp="model", mesh=mesh)
 p = init_moe(jax.random.PRNGKey(0), cfg, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
 
 y_dense, aux_d = apply_moe(p, x, dataclasses.replace(cfg, moe_impl="dense"),
                            AxisRules())
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y_ep, aux_e = jax.jit(lambda p_, x_: apply_moe(p_, x_, cfg, rules))(p, x)
 
 ok_y = bool(jnp.allclose(y_dense, y_ep, rtol=2e-4, atol=2e-5))
@@ -90,7 +91,7 @@ ok_aux = bool(jnp.abs(aux_d["moe_aux"] - aux_e["moe_aux"])
 def loss(p_):
     y, _ = apply_moe(p_, x, cfg, rules)
     return (y ** 2).sum()
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.grad(loss)(p)
 ok_g = all(bool(jnp.isfinite(v).all()) for v in jax.tree_util.tree_leaves(g))
 print("RESULT " + json.dumps({"y": ok_y, "aux": ok_aux, "grads": ok_g}))

@@ -397,6 +397,11 @@ def test_result_provenance_round_trips_through_store(tmp_path):
     assert prov["backend"] == "numpy" and prov["spec_digest"] == "d1"
     assert prov["timings"] == stats.timing
     assert prov["numpy"] == np.__version__
+    import jax
+    dev = jax.devices()[0]
+    assert prov["device"] == {"platform": dev.platform,
+                              "kind": dev.device_kind,
+                              "count": len(jax.devices())}
     store = JsonlStore(tmp_path / "r.jsonl")
     store.append(res)
     back = store.load()["k"]

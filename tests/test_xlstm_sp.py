@@ -10,7 +10,7 @@ _CHILD = r"""
 import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro._compat.jaxapi import shard_map
+from jax import shard_map
 from repro.models.xlstm import mlstm_sequential
 from repro.models.xlstm_sp import mlstm_context_parallel
 
