@@ -1,8 +1,6 @@
 """Benchmark harness: one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.  The roofline section reads
-the dry-run JSONs if present (run ``python -m repro.launch.dryrun --all``
-first for the full table).
+Prints ``name,us_per_call,derived`` CSV rows.
 
 ``--quick`` (the CI configuration) drops all ``time_us`` timings to a
 single repeat with no warmup, and modules that opt in via
@@ -38,7 +36,6 @@ MODULES = [
     "bench_workload",        # extracted-step replay + serving SLOs (after _simulation: appends to its artifact)
     "bench_compile",         # compile cache cold/warm/disk split + 1040-switch xl point (appends to the artifact)
     "bench_collectives",     # §2 refs [8,9]: LACIN collectives vs XLA
-    "roofline",              # §Roofline (from dry-run JSONs)
 ]
 
 

@@ -94,6 +94,7 @@ def all_to_all_lacin(x: jax.Array, axis_name: str, *, axis_size: int | None = No
 # all-gather
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("all_gather")
 def all_gather_lacin(x: jax.Array, axis_name: str, *, axis_size: int | None = None,
                      instance: str = "auto", tiled: bool = False) -> jax.Array:
     """All-gather this device's shard across ``axis_name``.
@@ -125,6 +126,7 @@ def all_gather_lacin(x: jax.Array, axis_name: str, *, axis_size: int | None = No
 # reduce-scatter
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("reduce_scatter")
 def reduce_scatter_lacin(x: jax.Array, axis_name: str, *, axis_size: int | None = None,
                          instance: str = "auto") -> jax.Array:
     """Reduce-scatter over ``axis_name``.

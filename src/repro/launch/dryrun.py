@@ -11,8 +11,7 @@ cell on the production meshes and extract the roofline terms.
     PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-1b --shape train_4k
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 
-Outputs one JSON per cell under --out (default results/dryrun/), consumed
-by benchmarks/roofline.py and EXPERIMENTS.md.
+Outputs one JSON per cell under --out (default results/dryrun/).
 """
 import argparse
 import json

@@ -15,7 +15,9 @@ around every compiled-engine program build.
 :mod:`.spans`       Chrome trace-event builders: phase spans, per-packet
                     hop spans, counter tracks, schema validation
 :mod:`.telemetry`   compile-vs-execute timing of jit programs
-                    (:func:`timed_compiled`) and the environment
+                    (:func:`timed_compiled`), the per-grid host spans
+                    (:func:`span`), the programs' scope maps
+                    (:func:`scope_maps`) and the environment
                     :func:`provenance` block study records persist
 :mod:`.export`      one-call composition: a traced replay ->
                     Perfetto-loadable JSON with one lane per switch and
@@ -29,6 +31,28 @@ statically-shaped ring buffers into its loop (contiguous
 the hot path).  On drained deterministic workloads (collective replays
 whose phases are matchings, one-shot permutations) the two engines'
 traces agree *exactly*; ``tests/test_obs.py`` pins that.
+
+Program tracing is separate from the simulated-time traces above: it
+names the host work and the device stages of the program itself, on the
+clock of a ``jax.profiler`` trace.  Every grid opens the host spans
+``study.resolve``, ``sweep.traffic``, ``sweep.pack``, ``sweep.tables``,
+``sweep.transfer``, ``sweep.acquire``, ``sweep.execute``, ``sweep.fetch``,
+``sweep.stats`` and ``study.records`` (``jax.profiler.TraceAnnotation``;
+their seconds also land in the grid's timing dict as ``<span>_s``).  The
+compiled cycle step names its stages with ``jax.named_scope``: ``rng``,
+``eject``, ``route``, ``arbitrate``, ``move``, and ``sample`` when time
+series are traced; the LACIN all-reduce names ``reduce_scatter`` and
+``all_gather``.  Capture them with::
+
+    import jax
+    with jax.profiler.trace("/tmp/prof"):
+        Study(spec).run()
+    # -> open the .xplane.pb (or pass create_perfetto_trace=True and open
+    #    the perfetto_trace.json.gz) in ui.perfetto.dev
+
+A device op carries only its HLO instruction name; :func:`scope_maps`
+gives each instruction's scope for every program acquired in the
+process.
 
 Quickstart::
 
@@ -44,14 +68,16 @@ from .trace import Trace, TraceConfig, derive_backlog
 from .spans import (counter_events, export_perfetto, packet_events,
                     phase_events, request_events, validate_trace_events)
 from .telemetry import (cache_dir, cache_stats, clear_caches, provenance,
-                        reset_cache_stats, timed_compiled)
+                        reset_cache_stats, scope_map, scope_maps, span,
+                        span_record, timed_compiled)
 from .export import link_classes, replay_trace_events
 
 __all__ = [
     "Trace", "TraceConfig", "derive_backlog",
     "counter_events", "export_perfetto", "packet_events", "phase_events",
     "request_events", "validate_trace_events",
-    "provenance", "timed_compiled",
+    "provenance", "timed_compiled", "span", "span_record", "scope_map",
+    "scope_maps",
     "cache_dir", "cache_stats", "clear_caches", "reset_cache_stats",
     "link_classes", "replay_trace_events",
 ]

@@ -37,11 +37,30 @@ of once-per-process:
   interpret a stored wall-clock number months later on different
   hardware.
 
+* :func:`span` names one stretch of host work: a
+  ``jax.profiler.TraceAnnotation`` on the profiler's host plane, on the
+  device trace's clock, whose ``perf_counter`` seconds also add up under
+  ``<name>_s`` in the open :func:`span_record`.  ``timed_compiled``
+  opens ``sweep.acquire`` (the memory/disk/compile lookup) and
+  ``sweep.execute`` (the call to ``block_until_ready``);
+  :func:`repro.sim.xengine.sweep` and ``Study._run_jax`` open the rest
+  (``sweep.traffic``, ``sweep.pack``, ``sweep.tables``,
+  ``sweep.transfer``, ``sweep.fetch``, ``sweep.stats``,
+  ``study.resolve``, ``study.records``) and merge the record into the
+  grid's timing dict.
+
+* :func:`scope_maps` maps, for every program the memory cache holds,
+  each optimized-HLO instruction to the ``jax.named_scope`` path it was
+  traced under, keyed by the module name the device trace shows and the
+  executable's fingerprint.  It is computed from the executable's HLO
+  text on request only.
+
 Timing dicts are plain JSON-scalars so they serialize into JSONL stores
 and BENCH artifacts unchanged::
 
     {"backend": "jax", "compile_s": 0.11, "execute_s": 0.74,
-     "total_s": 0.85, "compile_cached": "disk", "grid_points": 24}
+     "total_s": 0.85, "compile_cached": "disk", "grid_points": 24,
+     "sweep.acquire_s": 0.000012, "sweep.execute_s": 0.74, ...}
 """
 from __future__ import annotations
 
@@ -49,9 +68,11 @@ import hashlib
 import os
 import pickle
 import platform
+import re
 import tempfile
 import time
 from collections import OrderedDict
+from contextlib import contextmanager
 from functools import lru_cache
 from pathlib import Path
 
@@ -59,7 +80,8 @@ import numpy as np
 
 __all__ = ["timed_compiled", "provenance", "timing_dict", "cache_dir",
            "cache_stats", "reset_cache_stats", "clear_caches",
-           "disk_cache_entries", "CACHE_FORMAT"]
+           "disk_cache_entries", "CACHE_FORMAT", "span", "span_record",
+           "recorded_spans", "scope_map", "scope_maps"]
 
 #: Bump when the on-disk entry layout changes: old entries become
 #: unreadable garbage to the new code, so the version participates in
@@ -155,6 +177,121 @@ def timing_dict(backend: str, *, compile_s: float = 0.0,
         "compile_cached": (compile_cached if compile_cached else False),
         "grid_points": int(grid_points),
     }
+
+
+#: The open span records, innermost last (see :func:`span_record`).
+_RECORDS: list[dict] = []
+
+
+@contextmanager
+def span_record():
+    """The record :func:`span` adds its seconds to: the one already open
+    (so a sweep inside a study shares the study's record), else a new
+    one, open until the block ends.  Yields a flat dict of ``<span>_s``
+    floats, rounded like :func:`timing_dict`'s."""
+    if _RECORDS:
+        yield _RECORDS[-1]
+        return
+    rec: dict = {}
+    _RECORDS.append(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDS.pop()
+
+
+def recorded_spans() -> dict:
+    """A copy of the open :func:`span_record` (empty when none is
+    open): what a timing dict merges in once its spans have closed."""
+    return dict(_RECORDS[-1]) if _RECORDS else {}
+
+
+@contextmanager
+def span(name: str):
+    """Host work named ``name``: a ``jax.profiler.TraceAnnotation`` in
+    the profiler's trace (nothing is recorded when no profiler runs),
+    and its ``perf_counter`` seconds added to ``<name>_s`` of the open
+    :func:`span_record`, if any.  Costs microseconds: open it around
+    per-grid work, never per cycle."""
+    import jax
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        if _RECORDS:
+            rec = _RECORDS[-1]
+            key = f"{name}_s"
+            rec[key] = round(rec.get(key, 0.0)
+                             + time.perf_counter() - t0, 6)
+
+
+_HLO_INSTR = re.compile(r"^\s+(ROOT\s+)?(%[\w.\-]+) = (.*)$")
+_HLO_COMP = re.compile(r"^(?:ENTRY\s+)?(%[\w.\-]+)\s.*\{$")
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r"\bcalls=(%[\w.\-]+)")
+_HLO_TUPLE = re.compile(r"\btuple\([^%)]*(%[\w.\-]+)")
+
+
+def _module_key(compiled) -> str:
+    """``<module name>(<executable fingerprint, hex>)``.  The device trace
+    names a launch ``<module name>(<runtime program id>)``, an id the
+    executable does not expose; a trace's program is found by its module
+    name, and among programs of one name by its instruction names."""
+    exe = compiled.runtime_executable()
+    fp = exe.fingerprint
+    return f"{exe.hlo_modules()[0].name}({fp.hex() if fp else ''})"
+
+
+def scope_map(compiled) -> tuple[str, dict]:
+    """``(module key, {instruction: scope path})`` of one compiled
+    executable, from its optimized HLO text.
+
+    The scope path is the instruction's ``metadata={op_name=...}``: the
+    ``jax.named_scope`` names it was traced under, between the
+    transformation names (``jit(_run_loop)/while/body/.../route/gather``).
+    An instruction that calls a computation (a fusion) takes the path of
+    that computation's root, or of the root tuple's first operand, and
+    its own when the root has none.  Instruction names are unique within
+    a module, so fused and unfused instructions share one map."""
+    own: dict[str, str] = {}
+    calls: dict[str, str] = {}
+    roots: dict[str, str] = {}
+    tuple_first: dict[str, str] = {}
+    comp = None
+    for line in compiled.as_text().splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            c = _HLO_COMP.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        name, rest = m.group(2), m.group(3)
+        op = _HLO_OP_NAME.search(rest)
+        own[name] = op.group(1) if op else ""
+        callee = _HLO_CALLS.search(rest)
+        if callee is not None:
+            calls[name] = callee.group(1)
+        if m.group(1) and comp is not None:
+            roots[comp] = name
+            t = _HLO_TUPLE.search(rest)
+            if t is not None:
+                tuple_first[name] = t.group(1)
+
+    def path(name: str) -> str:
+        root = roots.get(calls.get(name))
+        root = tuple_first.get(root, root)
+        return (root and path(root)) or own[name]
+
+    return _module_key(compiled), {name: path(name) for name in own}
+
+
+def scope_maps() -> dict[str, dict]:
+    """:func:`scope_map` of every program :func:`timed_compiled` holds in
+    its memory cache (every program acquired in this process, up to the
+    cache's bound), fresh compiles and disk restores alike.  Computed on
+    each call."""
+    return dict(scope_map(compiled) for compiled in _CACHE.values())
 
 
 def _aval_key(args) -> tuple:
@@ -306,31 +443,9 @@ def _memory_insert(key, compiled) -> None:
     _CACHE[key] = compiled
 
 
-def timed_compiled(fn, static_arg, *args, grid_points: int = 1,
-                   key_extra=None) -> tuple:
-    """Call ``fn(static_arg, *args)`` — a ``jax.jit(...,
-    static_argnums=0)`` function — through the AOT path, returning
-    ``(output, timing)`` where ``timing`` separates program acquisition
-    from execution (:func:`timing_dict`).
-
-    Acquisition checks the in-process LRU first
-    (``compile_cached="memory"``, ``compile_s`` 0.0), then the on-disk
-    AOT layer (``compile_cached="disk"``, ``compile_s`` = deserialize
-    time — milliseconds, not seconds), and only then lowers + compiles
-    (``compile_cached`` ``False``), writing the fresh executable back to
-    disk for the next process.  A disk-restored executable is the same
-    machine code the fresh compile produced, so its results are
-    byte-identical (``tests/test_conformance.py`` pins this).
-    Execution is timed to completion (``block_until_ready``), so
-    ``execute_s`` is device time, not dispatch time.
-
-    ``static_arg=None`` calls ``fn(*args)`` / ``fn.lower(*args)`` — for
-    pre-specialized jitted callables (e.g. xengine's sharded runners,
-    whose static spec is baked into the function); pass the spec through
-    ``key_extra`` so the disk key still covers it.  ``key_extra`` is any
-    repr-able value mixed into the disk digest (see :func:`_disk_key`).
-    """
-    import jax
+def _acquire(fn, static_arg, args, key_extra) -> tuple:
+    """``(executable, compile_s, compile_cached)`` from the memory cache,
+    the disk layer, or a fresh compile (see :func:`timed_compiled`)."""
     key = (fn, static_arg, _aval_key(args), repr(key_extra))
     compile_s = 0.0
     cached: str | bool = False
@@ -360,9 +475,45 @@ def timed_compiled(fn, static_arg, *args, grid_points: int = 1,
             if path is not None:
                 _disk_store(path, compiled)
         _memory_insert(key, compiled)
-    t1 = time.perf_counter()
-    out = jax.block_until_ready(compiled(*args))
-    execute_s = time.perf_counter() - t1
+    return compiled, compile_s, cached
+
+
+def timed_compiled(fn, static_arg, *args, grid_points: int = 1,
+                   key_extra=None) -> tuple:
+    """Call ``fn(static_arg, *args)`` — a ``jax.jit(...,
+    static_argnums=0)`` function — through the AOT path, returning
+    ``(output, timing)`` where ``timing`` separates program acquisition
+    from execution (:func:`timing_dict`).
+
+    Acquisition checks the in-process LRU first
+    (``compile_cached="memory"``, ``compile_s`` 0.0), then the on-disk
+    AOT layer (``compile_cached="disk"``, ``compile_s`` = deserialize
+    time — milliseconds, not seconds), and only then lowers + compiles
+    (``compile_cached`` ``False``), writing the fresh executable back to
+    disk for the next process.  A disk-restored executable is the same
+    machine code the fresh compile produced, so its results are
+    byte-identical (``tests/test_conformance.py`` pins this).
+    Execution is timed to completion (``block_until_ready``), so
+    ``execute_s`` is device time, not dispatch time.
+
+    ``static_arg=None`` calls ``fn(*args)`` / ``fn.lower(*args)`` — for
+    pre-specialized jitted callables (e.g. xengine's sharded runners,
+    whose static spec is baked into the function); pass the spec through
+    ``key_extra`` so the disk key still covers it.  ``key_extra`` is any
+    repr-able value mixed into the disk digest (see :func:`_disk_key`).
+
+    Acquisition runs under the span ``sweep.acquire`` and the call under
+    ``sweep.execute`` (:func:`span`); the open :func:`span_record` gets
+    their seconds, and this timing dict only the fields above.
+    """
+    import jax
+    with span("sweep.acquire"):
+        compiled, compile_s, cached = _acquire(fn, static_arg, args,
+                                               key_extra)
+    with span("sweep.execute"):
+        t1 = time.perf_counter()
+        out = jax.block_until_ready(compiled(*args))
+        execute_s = time.perf_counter() - t1
     return out, timing_dict("jax", compile_s=compile_s,
                             execute_s=execute_s, compile_cached=cached,
                             grid_points=grid_points)

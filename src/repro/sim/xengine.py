@@ -69,7 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..obs.telemetry import timed_compiled
+from ..obs.telemetry import (recorded_spans, span, span_record,
+                             timed_compiled)
 from ..obs.trace import Trace, TraceConfig, derive_backlog
 from .engine import _DRAIN_SLACK
 from .link import LinkLoadCounter, LinkTable
@@ -317,312 +318,324 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         rand_bits = min(30 - x_bits, 16)
     src, dst, gen = pkt["src"], pkt["dst"], pkt["gen"]
     c = state.cycle
-    if spec.num_phases:
-        # Replays measure the whole run (the horizon is only the phase
-        # count); the window upper bound applies to open-loop drains.
-        in_window = c >= warmup                      # (B,) per-copy mask
-    else:
-        # The measurement horizon is the *runtime* ``h_eff``, not the
-        # (possibly bucket-padded) static ``spec.horizon``: a padded
-        # program measures exactly what the exact-shape program would.
-        in_window = (c >= warmup) & (c < pkt["h_eff"])
-    # One random word per queue lane and per terminal lane; mechanisms
-    # consume disjoint bit ranges of a word (threefry bits are
-    # independent), halving the per-cycle threefry work.  The stream is
-    # drawn *per fabric copy* from a key folded over the copy's global
-    # id: copy b's bits depend only on (base key, cycle, copy_id[b]) —
-    # never on how many copies share the program — so bucket-padding
-    # the batch or sharding it across devices is bit-identical to the
-    # exact-shape single-device program.  Copy 0 keeps the unfolded
-    # per-cycle key: a single-copy program then draws the stream this
-    # engine has always drawn, preserving every seed-era single-run
-    # result bit for bit.
-    ck = jax.random.fold_in(base_key, c)
-    per_copy = n * pv + n * t
-    folded = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
-        ck, pkt["copy_id"])
-    keys = jnp.where((pkt["copy_id"] == 0)[:, None], ck, folded)
-    bits = jax.vmap(lambda k: jax.random.bits(k, (per_copy,)))(keys)
-    lane_bits = bits[:, :n * pv].reshape(q_flat)
-    #                                  ^ high 16: ejection; low 16: arb
-    term_bits = bits[:, n * pv:].reshape(nt_flat)
-    #                                  ^ high bits: arb; low: Valiant mid
+    # Every op of the step is traced under exactly one named scope (rng,
+    # eject, route, arbitrate, move, sample), so a profile can give each
+    # stage's device time (repro.obs.telemetry.scope_maps); scopes change
+    # only op metadata, never the compiled program.
+    with jax.named_scope("rng"):
+        if spec.num_phases:
+            # Replays measure the whole run (the horizon is only the phase
+            # count); the window upper bound applies to open-loop drains.
+            in_window = c >= warmup                      # (B,) per-copy mask
+        else:
+            # The measurement horizon is the *runtime* ``h_eff``, not the
+            # (possibly bucket-padded) static ``spec.horizon``: a padded
+            # program measures exactly what the exact-shape program would.
+            in_window = (c >= warmup) & (c < pkt["h_eff"])
+        # One random word per queue lane and per terminal lane; mechanisms
+        # consume disjoint bit ranges of a word (threefry bits are
+        # independent), halving the per-cycle threefry work.  The stream is
+        # drawn *per fabric copy* from a key folded over the copy's global
+        # id: copy b's bits depend only on (base key, cycle, copy_id[b]) —
+        # never on how many copies share the program — so bucket-padding
+        # the batch or sharding it across devices is bit-identical to the
+        # exact-shape single-device program.  Copy 0 keeps the unfolded
+        # per-cycle key: a single-copy program then draws the stream this
+        # engine has always drawn, preserving every seed-era single-run
+        # result bit for bit.
+        ck = jax.random.fold_in(base_key, c)
+        per_copy = n * pv + n * t
+        folded = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+            ck, pkt["copy_id"])
+        keys = jnp.where((pkt["copy_id"] == 0)[:, None], ck, folded)
+        bits = jax.vmap(lambda k: jax.random.bits(k, (per_copy,)))(keys)
+        lane_bits = bits[:, :n * pv].reshape(q_flat)
+        #                                  ^ high 16: ejection; low 16: arb
+        term_bits = bits[:, n * pv:].reshape(nt_flat)
+        #                                  ^ high bits: arb; low: Valiant mid
 
-    # -- queue heads --------------------------------------------------------
-    lanes = jnp.arange(q_flat, dtype=_I32)
-    valid = state.occ > 0
-    head_slot = state.head % cap
-    h_pair = state.buf[lanes, head_slot]        # (Q, 2): pid, attr
-    pid = jnp.where(valid, h_pair[:, 0], 0)
-    h_attr = h_pair[:, 1]
-    h_mid = h_attr >> 8
-    h_phase = (h_attr >> 7) & 1
-    h_hops = h_attr & _MAX_HOPS
-    done = valid & (tables.sw_local == dst[pid]) & (h_phase == 1)
+    with jax.named_scope("eject"):
+        # -- queue heads ------------------------------------------------------
+        lanes = jnp.arange(q_flat, dtype=_I32)
+        valid = state.occ > 0
+        head_slot = state.head % cap
+        h_pair = state.buf[lanes, head_slot]        # (Q, 2): pid, attr
+        pid = jnp.where(valid, h_pair[:, 0], 0)
+        h_attr = h_pair[:, 1]
+        h_mid = h_attr >> 8
+        h_phase = (h_attr >> 7) & 1
+        h_hops = h_attr & _MAX_HOPS
+        done = valid & (tables.sw_local == dst[pid]) & (h_phase == 1)
 
-    # 1. ejection: up to eject_bw random winners per switch ----------------
-    # Winners are the eject_bw smallest unique (randbits, lane) keys among
-    # the done heads of each switch's (ports * VCs) lane block.  Small
-    # blocks use a pairwise rank (fewest dispatches); large blocks a
-    # sorted k-th-key threshold (O(pv log pv) beats O(pv^2)).  Both pick
-    # the same winners.
-    done2 = done.reshape(blocks, pv)
-    if spec.eject_bw <= 0:
-        # A stalled ejection port: nothing leaves (matches the oracle's
-        # arbitrate(..., k=0)); without this guard the sort-threshold
-        # branch below would index the k-th key at -1 and eject everything.
-        ej_win = jnp.zeros(q_flat, bool)
-    elif pv <= 32:
-        r2 = (lane_bits >> np.uint32(16)).astype(jnp.uint16
-                                                 ).reshape(blocks, pv)
-        idx = jnp.arange(pv)
-        before = (r2[:, None, :] < r2[:, :, None]) | (
-            (r2[:, None, :] == r2[:, :, None])
-            & (idx[None, :] < idx[:, None]))
-        rank = jnp.sum(before & done2[:, None, :], axis=2)
-        ej_win = (done2 & (rank < spec.eject_bw)).reshape(q_flat)
-    else:
-        e_bits = int(pv).bit_length()
-        ekey = (((lane_bits >> np.uint32(16)).astype(_I32)
-                 << e_bits) | tables.x_of_lane)
-        ekey = jnp.where(done, ekey, _INT32_MAX)
-        kth = jnp.sort(ekey.reshape(blocks, pv), axis=1)[
-            :, min(spec.eject_bw, pv) - 1]
-        ej_win = done & (ekey <= jnp.repeat(kth, pv))
+        # 1. ejection: up to eject_bw random winners per switch ---------------
+        # Winners are the eject_bw smallest unique (randbits, lane) keys among
+        # the done heads of each switch's (ports * VCs) lane block.  Small
+        # blocks use a pairwise rank (fewest dispatches); large blocks a
+        # sorted k-th-key threshold (O(pv log pv) beats O(pv^2)).  Both pick
+        # the same winners.
+        done2 = done.reshape(blocks, pv)
+        if spec.eject_bw <= 0:
+            # A stalled ejection port: nothing leaves (matches the oracle's
+            # arbitrate(..., k=0)); without this guard the sort-threshold
+            # branch below would index the k-th key at -1 and eject everything.
+            ej_win = jnp.zeros(q_flat, bool)
+        elif pv <= 32:
+            r2 = (lane_bits >> np.uint32(16)).astype(jnp.uint16
+                                                     ).reshape(blocks, pv)
+            idx = jnp.arange(pv)
+            before = (r2[:, None, :] < r2[:, :, None]) | (
+                (r2[:, None, :] == r2[:, :, None])
+                & (idx[None, :] < idx[:, None]))
+            rank = jnp.sum(before & done2[:, None, :], axis=2)
+            ej_win = (done2 & (rank < spec.eject_bw)).reshape(q_flat)
+        else:
+            e_bits = int(pv).bit_length()
+            ekey = (((lane_bits >> np.uint32(16)).astype(_I32)
+                     << e_bits) | tables.x_of_lane)
+            ekey = jnp.where(done, ekey, _INT32_MAX)
+            kth = jnp.sort(ekey.reshape(blocks, pv), axis=1)[
+                :, min(spec.eject_bw, pv) - 1]
+            ej_win = done & (ekey <= jnp.repeat(kth, pv))
 
-    ej_cnt = ej_win.reshape(b, n * pv).sum(axis=1, dtype=_I32)
-    if spec.log_deliveries:
-        # One contiguous row write per cycle; per-packet times are
-        # reconstructed on the host.  Orders of magnitude cheaper than a
-        # per-row scatter on XLA:CPU.
-        deliver = state.deliver
-        ej_log = lax.dynamic_update_slice(
-            state.ej_log, jnp.where(ej_win, pid, -1)[None, :], (c, 0))
-    else:
-        deliver = state.deliver.at[
-            jnp.where(ej_win, pid, m_flat)].set(c, mode="drop")
-        ej_log = state.ej_log
-    occ = state.occ - ej_win.astype(_I16)
-    head = state.head + ej_win.astype(_I16)
-    delivered_total = state.delivered_total + ej_cnt
-    delivered_win = state.delivered_win + jnp.where(in_window, ej_cnt, 0)
+        ej_cnt = ej_win.reshape(b, n * pv).sum(axis=1, dtype=_I32)
+        if spec.log_deliveries:
+            # One contiguous row write per cycle; per-packet times are
+            # reconstructed on the host.  Orders of magnitude cheaper than a
+            # per-row scatter on XLA:CPU.
+            deliver = state.deliver
+            ej_log = lax.dynamic_update_slice(
+                state.ej_log, jnp.where(ej_win, pid, -1)[None, :], (c, 0))
+        else:
+            deliver = state.deliver.at[
+                jnp.where(ej_win, pid, m_flat)].set(c, mode="drop")
+            ej_log = state.ej_log
+        occ = state.occ - ej_win.astype(_I16)
+        head = state.head + ej_win.astype(_I16)
+        delivered_total = state.delivered_total + ej_cnt
+        delivered_win = state.delivered_win + jnp.where(in_window, ej_cnt, 0)
 
-    # -- phase barrier (collective replay) ---------------------------------
-    # cur_phase[b] = completed phases of copy b, derived from the
-    # post-ejection delivered count against the per-copy cumulative phase
-    # sizes — the same-cycle release discipline of the oracle engine
-    # (a phase's closing delivery unblocks the next phase's injection in
-    # this very cycle).  phase_done records each phase's closing cycle.
-    if spec.num_phases:
-        cum = pkt["phase_cum"]                      # (B, num_phases)
-        done_p = delivered_total[:, None] >= cum
-        phase_done = jnp.where((state.phase_done < 0) & done_p, c,
-                               state.phase_done)
-        cur_phase = jnp.sum(done_p, axis=1).astype(_I32)   # (B,)
-    else:
-        phase_done = state.phase_done
+        # -- phase barrier (collective replay) --------------------------------
+        # cur_phase[b] = completed phases of copy b, derived from the
+        # post-ejection delivered count against the per-copy cumulative phase
+        # sizes — the same-cycle release discipline of the oracle engine
+        # (a phase's closing delivery unblocks the next phase's injection in
+        # this very cycle).  phase_done records each phase's closing cycle.
+        if spec.num_phases:
+            cum = pkt["phase_cum"]                      # (B, num_phases)
+            done_p = delivered_total[:, None] >= cum
+            phase_done = jnp.where((state.phase_done < 0) & done_p, c,
+                                   state.phase_done)
+            cur_phase = jnp.sum(done_p, axis=1).astype(_I32)   # (B,)
+        else:
+            phase_done = state.phase_done
 
-    # 2. transit requests --------------------------------------------------
-    transit = valid & ~done
-    sw_q = tables.sw_local
-    tgt = jnp.where(h_phase == 1, dst[pid], h_mid)
-    safe_tgt = jnp.where(transit & (tgt != sw_q), tgt, (sw_q + 1) % n)
-    t_port = tables.port_table[sw_q, safe_tgt]
+    with jax.named_scope("route"):
+        # 2. transit requests -------------------------------------------------
+        transit = valid & ~done
+        sw_q = tables.sw_local
+        tgt = jnp.where(h_phase == 1, dst[pid], h_mid)
+        safe_tgt = jnp.where(transit & (tgt != sw_q), tgt, (sw_q + 1) % n)
+        t_port = tables.port_table[sw_q, safe_tgt]
 
-    # 3. injection candidates + policy itinerary ---------------------------
-    cand = (pkt["blk_start"][tables.blk_idx] + tables.slot_of_term
-            + state.term_next * t)
-    inj_valid = cand < pkt["blk_end"][tables.blk_idx]
-    ip = jnp.where(inj_valid, cand, 0)
-    if spec.num_phases:
-        # Replay: gen is the packet's phase ordinal; it may inject once
-        # its copy has completed that many phases.
-        inj_valid &= gen[ip] <= cur_phase[tables.copybase_of_term // (n * p)]
-    else:
-        inj_valid &= gen[ip] <= c
+        # 3. injection candidates + policy itinerary --------------------------
+        cand = (pkt["blk_start"][tables.blk_idx] + tables.slot_of_term
+                + state.term_next * t)
+        inj_valid = cand < pkt["blk_end"][tables.blk_idx]
+        ip = jnp.where(inj_valid, cand, 0)
+        if spec.num_phases:
+            # Replay: gen is the packet's phase ordinal; it may inject once
+            # its copy has completed that many phases.
+            inj_valid &= gen[ip] <= cur_phase[
+                tables.copybase_of_term // (n * p)]
+        else:
+            inj_valid &= gen[ip] <= c
 
-    i_mid, i_phase = dst[ip], jnp.ones(nt_flat, _I32)
-    if spec.policy != "minimal" and n >= 3:
-        # Uniform intermediate avoiding {src, dst} (shift-remap).
-        s_i, d_i = src[ip], dst[ip]
-        lo = jnp.minimum(s_i, d_i)
-        hi = jnp.maximum(s_i, d_i)
-        r = ((term_bits & np.uint32(0x3FFF)) % np.uint32(n - 2)
-             ).astype(_I32)
-        r = r + (r >= lo)
-        r = r + (r >= hi)
-        # Degraded fabrics: a mid that died or fell outside the source's
-        # component collapses to the destination (route minimally rather
-        # than detour into a black hole).  comp_of_switch is all zeros
-        # pristine, so ``ok`` is constant-True there and the collapse is
-        # the identity — same sample bits, same results.
-        ok = (tables.comp_of_switch[r] == tables.comp_of_switch[s_i])
-        if spec.policy == "valiant":
-            i_mid = jnp.where(ok, r, d_i)
-            i_phase = jnp.where(ok, 0, 1).astype(_I32)
-        else:  # adaptive: congestion-threshold detour (UGAL-style)
-            per_port_occ = occ.reshape(n_links, v).sum(axis=1)
-            base = tables.copybase_of_term
+        i_mid, i_phase = dst[ip], jnp.ones(nt_flat, _I32)
+        if spec.policy != "minimal" and n >= 3:
+            # Uniform intermediate avoiding {src, dst} (shift-remap).
+            s_i, d_i = src[ip], dst[ip]
+            lo = jnp.minimum(s_i, d_i)
+            hi = jnp.maximum(s_i, d_i)
+            r = ((term_bits & np.uint32(0x3FFF)) % np.uint32(n - 2)
+                 ).astype(_I32)
+            r = r + (r >= lo)
+            r = r + (r >= hi)
+            # Degraded fabrics: a mid that died or fell outside the source's
+            # component collapses to the destination (route minimally rather
+            # than detour into a black hole).  comp_of_switch is all zeros
+            # pristine, so ``ok`` is constant-True there and the collapse is
+            # the identity — same sample bits, same results.
+            ok = (tables.comp_of_switch[r] == tables.comp_of_switch[s_i])
+            if spec.policy == "valiant":
+                i_mid = jnp.where(ok, r, d_i)
+                i_phase = jnp.where(ok, 0, 1).astype(_I32)
+            else:  # adaptive: congestion-threshold detour (UGAL-style)
+                per_port_occ = occ.reshape(n_links, v).sum(axis=1)
+                base = tables.copybase_of_term
 
-            def congestion(port_local):
-                link_local = s_i * p + port_local
-                backlog = per_port_occ[
-                    base + tables.feeder_local[link_local]]
-                return state.pressure[base + link_local] + backlog
+                def congestion(port_local):
+                    link_local = s_i * p + port_local
+                    backlog = per_port_occ[
+                        base + tables.feeder_local[link_local]]
+                    return state.pressure[base + link_local] + backlog
 
-            safe_d = jnp.where(d_i != s_i, d_i, (s_i + 1) % n)
-            c_min = congestion(tables.port_table[s_i, safe_d])
-            c_val = congestion(tables.port_table[s_i, r])
-            detour = (c_min > spec.weight * c_val + spec.threshold) & ok
-            i_mid = jnp.where(detour, r, d_i)
-            i_phase = jnp.where(detour, 0, 1).astype(_I32)
+                safe_d = jnp.where(d_i != s_i, d_i, (s_i + 1) % n)
+                c_min = congestion(tables.port_table[s_i, safe_d])
+                c_val = congestion(tables.port_table[s_i, r])
+                detour = (c_min > spec.weight * c_val + spec.threshold) & ok
+                i_mid = jnp.where(detour, r, d_i)
+                i_phase = jnp.where(detour, 0, 1).astype(_I32)
 
-    i_tgt = jnp.where(i_phase == 1, dst[ip], i_mid)
-    i_src = src[ip]
-    i_tgt = jnp.where(i_tgt != i_src, i_tgt, (i_src + 1) % n)
-    i_port = tables.port_table[i_src, i_tgt]
+        i_tgt = jnp.where(i_phase == 1, dst[ip], i_mid)
+        i_src = src[ip]
+        i_tgt = jnp.where(i_tgt != i_src, i_tgt, (i_src + 1) % n)
+        i_port = tables.port_table[i_src, i_tgt]
 
-    # 4. link arbitration with credit check --------------------------------
-    # Contender block per switch: its pv queue heads then its t terminals.
-    # The attribute word carries (mid, phase, hops-after-this-hop), so the
-    # requested VC class is derived from it: min(hops - 1, V-1).
-    act = jnp.concatenate([transit.reshape(blocks, pv),
-                           inj_valid.reshape(blocks, t)], axis=1)
-    port_x = jnp.concatenate([t_port.reshape(blocks, pv),
-                              i_port.reshape(blocks, t)], axis=1)
-    pid_x = jnp.concatenate([pid.reshape(blocks, pv),
-                             ip.reshape(blocks, t)], axis=1)
-    attr_x = jnp.concatenate([
-        _pack_attr(h_mid, h_phase, h_hops + 1).reshape(blocks, pv),
-        _pack_attr(i_mid, i_phase, jnp.ones(nt_flat, _I32)
-                   ).reshape(blocks, t)], axis=1)
-    vc_x = jnp.minimum((attr_x & _MAX_HOPS) - 1, v - 1)
+    with jax.named_scope("arbitrate"):
+        # 4. link arbitration with credit check -------------------------------
+        # Contender block per switch: its pv queue heads then its t terminals.
+        # The attribute word carries (mid, phase, hops-after-this-hop), so the
+        # requested VC class is derived from it: min(hops - 1, V-1).
+        act = jnp.concatenate([transit.reshape(blocks, pv),
+                               inj_valid.reshape(blocks, t)], axis=1)
+        port_x = jnp.concatenate([t_port.reshape(blocks, pv),
+                                  i_port.reshape(blocks, t)], axis=1)
+        pid_x = jnp.concatenate([pid.reshape(blocks, pv),
+                                 ip.reshape(blocks, t)], axis=1)
+        attr_x = jnp.concatenate([
+            _pack_attr(h_mid, h_phase, h_hops + 1).reshape(blocks, pv),
+            _pack_attr(i_mid, i_phase, jnp.ones(nt_flat, _I32)
+                       ).reshape(blocks, t)], axis=1)
+        vc_x = jnp.minimum((attr_x & _MAX_HOPS) - 1, v - 1)
 
-    # Credit check against the downstream (port, VC) queue of each
-    # contender's requested link.  The downstream (switch, input-port) of
-    # link (s, i) is ``feeder_local[s*p + i]`` — the same inverse-wire
-    # table that routes pushes, read in the other direction.
-    link_local_x = jnp.concatenate(
-        [(sw_q * p + t_port).reshape(blocks, pv),
-         (i_src * p + i_port).reshape(blocks, t)], axis=1)
-    dq = ((tables.copybase_of_block[:, None]
-           + tables.feeder_local[link_local_x]) * v + vc_x)
-    # Unwired slots (feeder_local == -1), including links a FailureSpec
-    # killed, are permanently credit-starved: well-formed routing never
-    # requests them, and this mask keeps any stray request from reading
-    # a garbage queue's occupancy and winning arbitration on it.
-    feas = act & (tables.feeder_local[link_local_x] >= 0) & (occ[dq] < cap)
+        # Credit check against the downstream (port, VC) queue of each
+        # contender's requested link.  The downstream (switch, input-port) of
+        # link (s, i) is ``feeder_local[s*p + i]`` — the same inverse-wire
+        # table that routes pushes, read in the other direction.
+        link_local_x = jnp.concatenate(
+            [(sw_q * p + t_port).reshape(blocks, pv),
+             (i_src * p + i_port).reshape(blocks, t)], axis=1)
+        dq = ((tables.copybase_of_block[:, None]
+               + tables.feeder_local[link_local_x]) * v + vc_x)
+        # Unwired slots (feeder_local == -1), including links a FailureSpec
+        # killed, are permanently credit-starved: well-formed routing never
+        # requests them, and this mask keeps any stray request from reading
+        # a garbage queue's occupancy and winning arbitration on it.
+        feas = act & (tables.feeder_local[link_local_x] >= 0) & (occ[dq] < cap)
 
-    # Arbitration randomness: transit lanes use the low half of their
-    # lane word (the high half fed ejection); terminal lanes use the top
-    # of their word (the bottom 14 bits fed the Valiant-mid sample).
-    rand = jnp.concatenate(
-        [((lane_bits & np.uint32(0xFFFF))
-          >> np.uint32(16 - rand_bits)).astype(_I32).reshape(blocks, pv),
-         (term_bits >> np.uint32(32 - rand_bits)).astype(_I32
-                                                         ).reshape(blocks, t)],
-        axis=1)
-    cls = (jnp.arange(x, dtype=_I32) >= pv).astype(_I32)[None, :]
-    packed = ((((cls << rand_bits) | rand) << x_bits) | jnp.arange(
-        x, dtype=_I32)[None, :]).astype(key_dtype)
-    # (blocks, x, p) one-hot expansion; one min-reduction per port gives
-    # the winning key and the winner's contender index in its low bits.
-    key_m = jnp.where(
-        feas[:, :, None] & (port_x[:, :, None] == jnp.arange(p)),
-        packed[:, :, None], key_dtype(sent))
-    minval_flat = jnp.min(key_m, axis=1).reshape(n_links).astype(_I32)
+        # Arbitration randomness: transit lanes use the low half of their
+        # lane word (the high half fed ejection); terminal lanes use the top
+        # of their word (the bottom 14 bits fed the Valiant-mid sample).
+        rand = jnp.concatenate(
+            [((lane_bits & np.uint32(0xFFFF))
+              >> np.uint32(16 - rand_bits)).astype(_I32).reshape(blocks, pv),
+             (term_bits >> np.uint32(32 - rand_bits)
+              ).astype(_I32).reshape(blocks, t)],
+            axis=1)
+        cls = (jnp.arange(x, dtype=_I32) >= pv).astype(_I32)[None, :]
+        packed = ((((cls << rand_bits) | rand) << x_bits) | jnp.arange(
+            x, dtype=_I32)[None, :]).astype(key_dtype)
+        # (blocks, x, p) one-hot expansion; one min-reduction per port gives
+        # the winning key and the winner's contender index in its low bits.
+        key_m = jnp.where(
+            feas[:, :, None] & (port_x[:, :, None] == jnp.arange(p)),
+            packed[:, :, None], key_dtype(sent))
+        minval_flat = jnp.min(key_m, axis=1).reshape(n_links).astype(_I32)
 
-    if spec.policy == "adaptive":
-        # EWMA of requested (pre-credit) demand — only adaptive reads it.
-        req = act[:, :, None] & (port_x[:, :, None] == jnp.arange(p))
-        demand = jnp.sum(req, axis=1).reshape(n_links)
-        pressure = (state.pressure
-                    + spec.alpha * (demand - state.pressure))
-    else:
-        pressure = state.pressure
+        if spec.policy == "adaptive":
+            # EWMA of requested (pre-credit) demand — only adaptive reads it.
+            req = act[:, :, None] & (port_x[:, :, None] == jnp.arange(p))
+            demand = jnp.sum(req, axis=1).reshape(n_links)
+            pressure = (state.pressure
+                        + spec.alpha * (demand - state.pressure))
+        else:
+            pressure = state.pressure
 
-    # 5. movement ----------------------------------------------------------
-    # Transit pop: queue lane q wins iff the winner of its requested link
-    # is contender q itself (sentinel's index field cannot match).
-    win_t = transit & ((minval_flat[tables.linkbase_of_lane + t_port]
-                        & x_mask) == tables.x_of_lane)
-    occ = occ - win_t.astype(_I16)
-    head = head + win_t.astype(_I16)
+    with jax.named_scope("move"):
+        # 5. movement ---------------------------------------------------------
+        # Transit pop: queue lane q wins iff the winner of its requested link
+        # is contender q itself (sentinel's index field cannot match).
+        win_t = transit & ((minval_flat[tables.linkbase_of_lane + t_port]
+                            & x_mask) == tables.x_of_lane)
+        occ = occ - win_t.astype(_I16)
+        head = head + win_t.astype(_I16)
 
-    # Injection advance: terminal lane wins iff the winner of its link is
-    # contender pv + (lane's slot within the switch).
-    i_win = inj_valid & ((minval_flat[tables.linkbase_of_term + i_port]
-                          & x_mask) == pv + tables.slot_of_term)
-    term_next = state.term_next + i_win.astype(_I32)
+        # Injection advance: terminal lane wins iff the winner of its link is
+        # contender pv + (lane's slot within the switch).
+        i_win = inj_valid & ((minval_flat[tables.linkbase_of_term + i_port]
+                              & x_mask) == pv + tables.slot_of_term)
+        term_next = state.term_next + i_win.astype(_I32)
 
-    # Push as a gather: queue (sw', p', vc') receives the winner of its
-    # feeder link (the wire into input port p') when the VC matches.
-    mv = minval_flat[tables.feeder_flat]
-    recv_x = tables.feeder_xbase + (mv & x_mask)
-    pair_x = jnp.stack([pid_x, attr_x], axis=-1).reshape(blocks * x, 2)
-    pair_w = pair_x[recv_x]                     # (Q, 2): pid, attr
-    pid_w, attr_w = pair_w[:, 0], pair_w[:, 1]
-    vc_w = jnp.minimum((attr_w & _MAX_HOPS) - 1, v - 1)
-    recv = tables.wired_q & (mv != sent) & (vc_w == tables.vc_of_lane)
-    # Phase flips on arrival at the Valiant intermediate — which, seen
-    # from the receiving queue, is simply its own switch.
-    attr_w = jnp.where(((attr_w & (1 << 7)) == 0)
-                       & ((attr_w >> 8) == tables.sw_local),
-                       attr_w | (1 << 7), attr_w)
+        # Push as a gather: queue (sw', p', vc') receives the winner of its
+        # feeder link (the wire into input port p') when the VC matches.
+        mv = minval_flat[tables.feeder_flat]
+        recv_x = tables.feeder_xbase + (mv & x_mask)
+        pair_x = jnp.stack([pid_x, attr_x], axis=-1).reshape(blocks * x, 2)
+        pair_w = pair_x[recv_x]                     # (Q, 2): pid, attr
+        pid_w, attr_w = pair_w[:, 0], pair_w[:, 1]
+        vc_w = jnp.minimum((attr_w & _MAX_HOPS) - 1, v - 1)
+        recv = tables.wired_q & (mv != sent) & (vc_w == tables.vc_of_lane)
+        # Phase flips on arrival at the Valiant intermediate — which, seen
+        # from the receiving queue, is simply its own switch.
+        attr_w = jnp.where(((attr_w & (1 << 7)) == 0)
+                           & ((attr_w >> 8) == tables.sw_local),
+                           attr_w | (1 << 7), attr_w)
 
-    slot = (head + occ) % cap
-    onehot = (jnp.arange(cap, dtype=_I32)[None, :] == slot[:, None]
-              ) & recv[:, None]
-    buf = jnp.where(
-        onehot[:, :, None],
-        jnp.stack([pid_w, attr_w], axis=-1)[:, None, :], state.buf)
-    occ = occ + recv.astype(_I16)
-    # Ring-buffer heads live in int16 (the dtype diet halves the hot
-    # state); stored mod capacity so they never overflow over long runs.
-    head = head % cap
+        slot = (head + occ) % cap
+        onehot = (jnp.arange(cap, dtype=_I32)[None, :] == slot[:, None]
+                  ) & recv[:, None]
+        buf = jnp.where(
+            onehot[:, :, None],
+            jnp.stack([pid_w, attr_w], axis=-1)[:, None, :], state.buf)
+        occ = occ + recv.astype(_I16)
+        # Ring-buffer heads live in int16 (the dtype diet halves the hot
+        # state); stored mod capacity so they never overflow over long runs.
+        head = head % cap
 
-    has_w = minval_flat != sent
-    load_total = state.load_total + has_w.astype(_I32)
-    load_window = state.load_window + (
-        has_w & in_window[tables.copy_of_link]).astype(_I32)
+        has_w = minval_flat != sent
+        load_total = state.load_total + has_w.astype(_I32)
+        load_window = state.load_window + (
+            has_w & in_window[tables.copy_of_link]).astype(_I32)
 
-    # -- trace sampling (end of cycle c, after movement) -------------------
-    # Gated at Python trace time on the static spec, so an untraced
-    # program is byte-for-byte the pre-trace program.  Row writes are
-    # read-modify-write: an out-of-range dynamic_update_slice start
-    # clamps (it would silently overwrite the last row), so the row is
-    # first read and only replaced when this cycle really samples.
-    if spec.trace_stride:
-        row = jnp.minimum(c // spec.trace_stride, spec.trace_samples - 1)
-        write = ((c % spec.trace_stride) == 0) & (
-            c // spec.trace_stride < spec.trace_samples)
+    with jax.named_scope("sample"):
+        # -- trace sampling (end of cycle c, after movement) ------------------
+        # Gated at Python trace time on the static spec, so an untraced
+        # program is byte-for-byte the pre-trace program.  Row writes are
+        # read-modify-write: an out-of-range dynamic_update_slice start
+        # clamps (it would silently overwrite the last row), so the row is
+        # first read and only replaced when this cycle really samples.
+        if spec.trace_stride:
+            row = jnp.minimum(c // spec.trace_stride, spec.trace_samples - 1)
+            write = ((c % spec.trace_stride) == 0) & (
+                c // spec.trace_stride < spec.trace_samples)
 
-        def _row_write(rbuf, vec):
-            cur = lax.dynamic_slice_in_dim(rbuf, row, 1, axis=0)
-            new = jnp.where(write, vec[None, :].astype(rbuf.dtype), cur)
-            return lax.dynamic_update_slice_in_dim(rbuf, new, row, axis=0)
+            def _row_write(rbuf, vec):
+                cur = lax.dynamic_slice_in_dim(rbuf, row, 1, axis=0)
+                new = jnp.where(write, vec[None, :].astype(rbuf.dtype), cur)
+                return lax.dynamic_update_slice_in_dim(rbuf, new, row, axis=0)
 
-        cur_c = lax.dynamic_slice_in_dim(state.tr_cycle, row, 1, axis=0)
-        tr_cycle = lax.dynamic_update_slice_in_dim(
-            state.tr_cycle, jnp.where(write, c.astype(_I32), cur_c),
-            row, axis=0)
-        tr_link = _row_write(state.tr_link, load_total)
-        tr_occ = _row_write(state.tr_occ,
-                            occ.reshape(blocks, pv).sum(axis=1))
-        tr_inj = _row_write(state.tr_inj,
-                            term_next.reshape(blocks, t).sum(axis=1))
-        tr_del = _row_write(state.tr_del, delivered_total)
-    else:
-        tr_cycle, tr_link = state.tr_cycle, state.tr_link
-        tr_occ, tr_inj, tr_del = state.tr_occ, state.tr_inj, state.tr_del
+            cur_c = lax.dynamic_slice_in_dim(state.tr_cycle, row, 1, axis=0)
+            tr_cycle = lax.dynamic_update_slice_in_dim(
+                state.tr_cycle, jnp.where(write, c.astype(_I32), cur_c),
+                row, axis=0)
+            tr_link = _row_write(state.tr_link, load_total)
+            tr_occ = _row_write(state.tr_occ,
+                                occ.reshape(blocks, pv).sum(axis=1))
+            tr_inj = _row_write(state.tr_inj,
+                                term_next.reshape(blocks, t).sum(axis=1))
+            tr_del = _row_write(state.tr_del, delivered_total)
+        else:
+            tr_cycle, tr_link = state.tr_cycle, state.tr_link
+            tr_occ, tr_inj, tr_del = state.tr_occ, state.tr_inj, state.tr_del
 
-    return _State(buf=buf, head=head, occ=occ, deliver=deliver,
-                  ej_log=ej_log, term_next=term_next, pressure=pressure,
-                  load_total=load_total, load_window=load_window,
-                  delivered_total=delivered_total,
-                  delivered_win=delivered_win, phase_done=phase_done,
-                  cycle=c + 1, tr_cycle=tr_cycle, tr_link=tr_link,
-                  tr_occ=tr_occ, tr_inj=tr_inj, tr_del=tr_del)
+    with jax.named_scope("move"):     # the one op left: cycle c + 1
+        return _State(buf=buf, head=head, occ=occ, deliver=deliver,
+                      ej_log=ej_log, term_next=term_next, pressure=pressure,
+                      load_total=load_total, load_window=load_window,
+                      delivered_total=delivered_total,
+                      delivered_win=delivered_win, phase_done=phase_done,
+                      cycle=c + 1, tr_cycle=tr_cycle, tr_link=tr_link,
+                      tr_occ=tr_occ, tr_inj=tr_inj, tr_del=tr_del)
 
 
 def _run_loop(spec: XSpec, tables: _Tables, pkt: dict, key: jax.Array,
@@ -819,6 +832,7 @@ def _build_tables(topo: SimTopology, links: LinkTable, b: int,
         copy_of_link=as_i32(link_ids // (n * p)))
 
 
+@span_record()
 def sweep(topo: SimTopology, policy, traffic_factory: Callable,
           loads: Sequence[float], *, seeds: Sequence[int] = (0,),
           terminals: int | None = None, eject_bw: int | None = None,
@@ -846,8 +860,12 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
 
     Every point's stats carry a shared ``timing`` record splitting the
     program's compile time from its execution
-    (:func:`repro.obs.telemetry.timed_compiled`).  ``trace`` (anything
-    :meth:`repro.obs.TraceConfig.coerce` accepts) compiles statically
+    (:func:`repro.obs.telemetry.timed_compiled`), and the seconds of the
+    sweep's host spans (``sweep.traffic``, ``sweep.pack``,
+    ``sweep.tables``, ``sweep.transfer``, ``sweep.acquire``,
+    ``sweep.execute``, ``sweep.fetch``, ``sweep.stats``; see
+    :func:`repro.obs.telemetry.span`) under ``<span>_s``.  ``trace``
+    (anything :meth:`repro.obs.TraceConfig.coerce` accepts) compiles statically
     shaped time-series ring buffers into the loop — per-point
     :class:`~repro.obs.Trace` objects land on ``stats.trace``.  Packet
     spans (``TraceConfig.packets``) are a numpy-engine feature and are
@@ -873,12 +891,13 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
     policy = _resolve_policy(policy)
     seeded_factory = _accepts_seed(traffic_factory)
     n = topo.num_switches
-    grid: list[tuple[float, int, Traffic]] = []
-    for load in loads:
-        for seed in seeds:
-            tr = (traffic_factory(load, seed) if seeded_factory
-                  else traffic_factory(load))
-            grid.append((load, seed, tr))
+    with span("sweep.traffic"):
+        grid: list[tuple[float, int, Traffic]] = []
+        for load in loads:
+            for seed in seeds:
+                tr = (traffic_factory(load, seed) if seeded_factory
+                      else traffic_factory(load))
+                grid.append((load, seed, tr))
     if not grid:
         return []
 
@@ -912,8 +931,9 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
 
     sizes = [tr.num_packets for _, _, tr in grid]
     bases = np.concatenate([[0], np.cumsum(sizes)[:-1]]).astype(np.int64)
-    packed = [_pack_traffic(tr, n, int(bases[i]))
-              for i, (_, _, tr) in enumerate(grid)]
+    with span("sweep.pack"):
+        packed = [_pack_traffic(tr, n, int(bases[i]))
+                  for i, (_, _, tr) in enumerate(grid)]
     # One program = one horizon.  cycles= pins it; otherwise take the max
     # generation window over the grid so no point's traffic is truncated
     # (points with shorter windows simply stop generating early).
@@ -953,9 +973,9 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
         # cutoff, so allocate for the worst case (capped by max_samples);
         # unwritten rows stay at the -1 sentinel and are dropped below.
         # Budgets derive from the *exact* span — padded cycles never run.
-        span = cutoff if drain else horizon
+        sampled = cutoff if drain else horizon
         trace_samples = min(trace_cfg.max_samples,
-                            (max(span, 1) - 1) // trace_cfg.stride + 1)
+                            (max(sampled, 1) - 1) // trace_cfg.stride + 1)
     spec = XSpec(
         n=n, ports=topo.num_ports, vcs=num_vcs, cap=queue_capacity,
         terminals=terminals,
@@ -968,56 +988,62 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
         trace_stride=0 if trace_cfg is None else trace_cfg.stride,
         trace_samples=0 if trace_cfg is None else trace_samples)
 
-    links = LinkTable.for_topology(topo, num_vcs)
-    tables = _build_tables(topo, links, b_pad // ndev, terminals, num_vcs)
+    with span("sweep.tables"):
+        links = LinkTable.for_topology(topo, num_vcs)
+        tables = _build_tables(topo, links, b_pad // ndev, terminals, num_vcs)
 
-    flat_np = {k: (np.concatenate([pk[k] for pk in packed])
-                   if packed[0][k].ndim else
-                   np.asarray([pk[k] for pk in packed]))
-               for k in packed[0]}
-    # Bucket the flat packet axis too, with inert padding slots: their
-    # generation time is past any horizon, so a padded slot never becomes
-    # an injection candidate (this also covers the all-empty grid, whose
-    # gathers need at least one in-range slot).  Padded *copies* carry
-    # empty source blocks, zero real packets, and warmup 0.
-    m_total = int(flat_np["src"].size)
-    m_pad = _bucket_count(max(m_total, 1)) if bucket else max(m_total, 1)
-    flat_np["src"] = np.concatenate(
-        [flat_np["src"], np.zeros(m_pad - m_total, np.int32)])
-    flat_np["dst"] = np.concatenate(
-        [flat_np["dst"], np.full(m_pad - m_total, min(1, n - 1), np.int32)])
-    flat_np["gen"] = np.concatenate(
-        [flat_np["gen"], np.full(m_pad - m_total, _PAD_GEN, np.int32)])
-    pad_b = b_pad - b_real
-    flat_np["blk_start"] = np.concatenate(
-        [flat_np["blk_start"], np.zeros(pad_b * n, np.int32)])
-    flat_np["blk_end"] = np.concatenate(
-        [flat_np["blk_end"], np.zeros(pad_b * n, np.int32)])
-    flat_np["m_real"] = np.concatenate(
-        [flat_np["m_real"], np.zeros(pad_b, np.int32)])
-    if replaying:
-        # Per-copy cumulative phase sizes, padded to the shared static
-        # phase count (padding phases are empty and complete instantly).
-        flat_np["phase_cum"] = np.concatenate(
-            [np.stack([w.phase_cum(num_phases) for w in wls]),
-             np.zeros((pad_b, num_phases))]).astype(np.int32)
-    # Global copy ids (the per-copy RNG fold keys) plus the runtime
-    # measurement bounds — per-copy so they shard with the batch.
-    flat_np["copy_id"] = np.arange(b_pad, dtype=np.int32)
-    flat_np["h_eff"] = np.full(b_pad, horizon, np.int32)
-    flat_np["cutoff_eff"] = np.full(b_pad, cutoff, np.int32)
+    with span("sweep.pack"):
+        flat_np = {k: (np.concatenate([pk[k] for pk in packed])
+                       if packed[0][k].ndim else
+                       np.asarray([pk[k] for pk in packed]))
+                   for k in packed[0]}
+        # Bucket the flat packet axis too, with inert padding slots: their
+        # generation time is past any horizon, so a padded slot never becomes
+        # an injection candidate (this also covers the all-empty grid, whose
+        # gathers need at least one in-range slot).  Padded *copies* carry
+        # empty source blocks, zero real packets, and warmup 0.
+        m_total = int(flat_np["src"].size)
+        m_pad = _bucket_count(max(m_total, 1)) if bucket else max(m_total, 1)
+        flat_np["src"] = np.concatenate(
+            [flat_np["src"], np.zeros(m_pad - m_total, np.int32)])
+        flat_np["dst"] = np.concatenate(
+            [flat_np["dst"],
+             np.full(m_pad - m_total, min(1, n - 1), np.int32)])
+        flat_np["gen"] = np.concatenate(
+            [flat_np["gen"], np.full(m_pad - m_total, _PAD_GEN, np.int32)])
+        pad_b = b_pad - b_real
+        flat_np["blk_start"] = np.concatenate(
+            [flat_np["blk_start"], np.zeros(pad_b * n, np.int32)])
+        flat_np["blk_end"] = np.concatenate(
+            [flat_np["blk_end"], np.zeros(pad_b * n, np.int32)])
+        flat_np["m_real"] = np.concatenate(
+            [flat_np["m_real"], np.zeros(pad_b, np.int32)])
+        if replaying:
+            # Per-copy cumulative phase sizes, padded to the shared static
+            # phase count (padding phases are empty and complete instantly).
+            flat_np["phase_cum"] = np.concatenate(
+                [np.stack([w.phase_cum(num_phases) for w in wls]),
+                 np.zeros((pad_b, num_phases))]).astype(np.int32)
+        # Global copy ids (the per-copy RNG fold keys) plus the runtime
+        # measurement bounds — per-copy so they shard with the batch.
+        flat_np["copy_id"] = np.arange(b_pad, dtype=np.int32)
+        flat_np["h_eff"] = np.full(b_pad, horizon, np.int32)
+        flat_np["cutoff_eff"] = np.full(b_pad, cutoff, np.int32)
 
-    # The persistent compile cache keys on content, not object identity:
-    # fold the (replicated) topology tables into the entry digest so two
-    # fabrics that merely share shapes never alias an entry.
-    dig = hashlib.sha256()
-    for a in tables:
-        dig.update(np.asarray(a).tobytes())
-    tab_digest = dig.hexdigest()
+    with span("sweep.tables"):
+        # The persistent compile cache keys on content, not object identity:
+        # fold the (replicated) topology tables into the entry digest so two
+        # fabrics that merely share shapes never alias an entry.
+        dig = hashlib.sha256()
+        for a in tables:
+            dig.update(np.asarray(a).tobytes())
+        tab_digest = dig.hexdigest()
 
-    flat = {k: jnp.asarray(a) for k, a in flat_np.items()}
-    key = jax.random.PRNGKey(hash(tuple(s for _, s, _ in grid)) & 0x7FFFFFFF)
-    warm_j = jnp.asarray(np.asarray(warmups + [0] * pad_b, np.int32))
+    with span("sweep.transfer"):
+        flat = {k: jnp.asarray(a) for k, a in flat_np.items()}
+        key = jax.random.PRNGKey(
+            hash(tuple(s for _, s, _ in grid)) & 0x7FFFFFFF)
+        warm_j = jnp.asarray(np.asarray(warmups + [0] * pad_b, np.int32))
     if ndev > 1:
         runner = _sharded_runner(spec, ndev, tuple(sorted(flat)))
         out, timing = timed_compiled(
@@ -1027,112 +1053,116 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
         out, timing = timed_compiled(
             _run_flat, spec, tables, flat, key, warm_j,
             grid_points=b_real, key_extra=tab_digest)
-    out = jax.tree_util.tree_map(np.asarray, out)
-    if ndev > 1:
-        # Host reassembly: shard outputs carry a leading device axis over
-        # contiguous copy blocks, so per-copy/per-link vectors flatten
-        # straight back into global copy-major order and ejection-log
-        # rows concatenate along the lane axis.  Delivery records hold
-        # *global* packet ids and are disjoint across shards (-1
-        # elsewhere), so an axis-0 max merges them.
-        out["deliver"] = out["deliver"].max(axis=0)
-        out["ej_log"] = np.concatenate(list(out["ej_log"]), axis=1)
-        for k in ("load_total", "load_window", "delivered_total",
-                  "delivered_in_window", "in_flight"):
-            out[k] = out[k].reshape(-1)
-        out["phase_done"] = out["phase_done"].reshape(b_pad, -1)
-        out["cycle"] = out["cycle"].max()
+    with span("sweep.fetch"):
+        out = jax.tree_util.tree_map(np.asarray, out)
+        if ndev > 1:
+            # Host reassembly: shard outputs carry a leading device axis over
+            # contiguous copy blocks, so per-copy/per-link vectors flatten
+            # straight back into global copy-major order and ejection-log
+            # rows concatenate along the lane axis.  Delivery records hold
+            # *global* packet ids and are disjoint across shards (-1
+            # elsewhere), so an axis-0 max merges them.
+            out["deliver"] = out["deliver"].max(axis=0)
+            out["ej_log"] = np.concatenate(list(out["ej_log"]), axis=1)
+            for k in ("load_total", "load_window", "delivered_total",
+                      "delivered_in_window", "in_flight"):
+                out[k] = out[k].reshape(-1)
+            out["phase_done"] = out["phase_done"].reshape(b_pad, -1)
+            out["cycle"] = out["cycle"].max()
 
-    total_m = max(1, int(sum(sizes)))
-    if log_deliveries:
-        # Reconstruct per-packet delivery cycles from the per-cycle
-        # ejection log: row c holds the pids ejected at cycle c.
-        log = out["ej_log"].ravel()
-        q_per_cycle = out["ej_log"].shape[1]
-        deliver_all = np.full(total_m, -1, np.int64)
-        hit = np.flatnonzero(log >= 0)
-        deliver_all[log[hit]] = hit // q_per_cycle
-    else:
-        deliver_all = out["deliver"].astype(np.int64)
+    with span("sweep.stats"):
+        total_m = max(1, int(sum(sizes)))
+        if log_deliveries:
+            # Reconstruct per-packet delivery cycles from the per-cycle
+            # ejection log: row c holds the pids ejected at cycle c.
+            log = out["ej_log"].ravel()
+            q_per_cycle = out["ej_log"].shape[1]
+            deliver_all = np.full(total_m, -1, np.int64)
+            hit = np.flatnonzero(log >= 0)
+            deliver_all[log[hit]] = hit // q_per_cycle
+        else:
+            deliver_all = out["deliver"].astype(np.int64)
 
-    n_links = n * topo.num_ports
-    if trace_cfg is not None:
-        tr_valid = np.flatnonzero(out["tr_cycle"] >= 0)
-        tr_cycles = out["tr_cycle"][tr_valid].astype(np.int64)
-    results: list[RunStats] = []
-    for i, (load, seed, tr) in enumerate(grid):
-        m = int(packed[i]["m_real"])
-        delivered_total = int(out["delivered_total"][i])
-        if drain and delivered_total < m:
-            raise RuntimeError(
-                f"{topo.name}/{policy.name}: {m - delivered_total} packets "
-                f"undelivered after {int(out['cycle'])} cycles "
-                f"(deadlock or cutoff too small)")
-        counter = LinkLoadCounter(links)
-        counter.total = out["load_total"][
-            i * n_links:(i + 1) * n_links].astype(np.int64)
-        counter.window = out["load_window"][
-            i * n_links:(i + 1) * n_links].astype(np.int64)
-        deliver = deliver_all[int(bases[i]):int(bases[i]) + m]
-        gen_arg = packed[i]["gen"][:m].astype(np.int64)
-        cycles_arg = max(horizon, 1)
-        if replaying:
-            # Measure over the replay's own timeline (see
-            # metrics.replay_timeline): horizon = completion cycle,
-            # generation = the cycle each packet's phase released.
-            pd = out["phase_done"][i, :wls[i].num_phases]
-            cycles_arg, gen_arg = replay_timeline(pd, gen_arg)
-        stats = build_stats(
-            topology=topo, policy=policy, traffic=tr,
-            cycles=cycles_arg, warmup=int(warmups[i]),
-            terminals=terminals, gen=gen_arg,
-            deliver=deliver, link_counter=counter,
-            delivered_in_window=int(out["delivered_in_window"][i]),
-            in_flight=int(out["in_flight"][i]))
-        if replaying:
-            attach_replay(stats, wls[i],
-                          out["phase_done"][i, :wls[i].num_phases])
-        if tr.request is not None:
-            # Serving metrics need request ids in the engine's packet
-            # order.  Recompute _pack_traffic's permutation (a stable
-            # lexsort over identical inputs — bit-identical to the one
-            # the packing used) host-side; the compiled program never
-            # sees the request array.
-            req = np.asarray(tr.request, dtype=np.int64)
-            src64 = tr.src.astype(np.int64)
-            gen64 = tr.gen.astype(np.int64)
-            sort_key = src64 * (gen64.max(initial=0) + 1) + gen64
-            if not np.all(sort_key[1:] >= sort_key[:-1]):
-                req = req[np.lexsort((tr.gen, tr.src))]
-            attach_serving(stats, req, packed[i]["gen"][:m].astype(np.int64),
-                           deliver, slo=tr.slo)
-        stats.timing = timing
+        n_links = n * topo.num_ports
         if trace_cfg is not None:
-            # Slice copy i's columns out of the flat ring buffers; block
-            # bounds come back to local pid space by removing the copy's
-            # packet-id base.
-            injected = out["tr_inj"][tr_valid][:, i * n:(i + 1) * n
-                                               ].astype(np.int64)
-            backlog = derive_backlog(
-                tr_cycles, injected,
-                packed[i]["gen"][:m].astype(np.int64),
-                packed[i]["blk_start"].astype(np.int64) - int(bases[i]),
-                packed[i]["blk_end"].astype(np.int64) - int(bases[i]),
-                phase_done=(out["phase_done"][i, :wls[i].num_phases]
-                            if replaying else None))
-            stats.trace = Trace(
-                stride=trace_cfg.stride, cycles=tr_cycles,
-                link_load=out["tr_link"][tr_valid][
-                    :, i * n_links:(i + 1) * n_links],
-                queue_occ=out["tr_occ"][tr_valid][:, i * n:(i + 1) * n],
-                injected=injected,
-                delivered=out["tr_del"][tr_valid][:, i],
-                backlog=backlog,
-                meta={"topology": topo.name, "policy": policy.name,
-                      "backend": "jax", "num_switches": n,
-                      "num_ports": topo.num_ports, "terminals": terminals,
-                      "load": load, "seed": seed})
-        results.append(stats)
+            tr_valid = np.flatnonzero(out["tr_cycle"] >= 0)
+            tr_cycles = out["tr_cycle"][tr_valid].astype(np.int64)
+        results: list[RunStats] = []
+        for i, (load, seed, tr) in enumerate(grid):
+            m = int(packed[i]["m_real"])
+            delivered_total = int(out["delivered_total"][i])
+            if drain and delivered_total < m:
+                raise RuntimeError(
+                    f"{topo.name}/{policy.name}: {m - delivered_total} "
+                    f"packets undelivered after {int(out['cycle'])} cycles "
+                    f"(deadlock or cutoff too small)")
+            counter = LinkLoadCounter(links)
+            counter.total = out["load_total"][
+                i * n_links:(i + 1) * n_links].astype(np.int64)
+            counter.window = out["load_window"][
+                i * n_links:(i + 1) * n_links].astype(np.int64)
+            deliver = deliver_all[int(bases[i]):int(bases[i]) + m]
+            gen_arg = packed[i]["gen"][:m].astype(np.int64)
+            cycles_arg = max(horizon, 1)
+            if replaying:
+                # Measure over the replay's own timeline (see
+                # metrics.replay_timeline): horizon = completion cycle,
+                # generation = the cycle each packet's phase released.
+                pd = out["phase_done"][i, :wls[i].num_phases]
+                cycles_arg, gen_arg = replay_timeline(pd, gen_arg)
+            stats = build_stats(
+                topology=topo, policy=policy, traffic=tr,
+                cycles=cycles_arg, warmup=int(warmups[i]),
+                terminals=terminals, gen=gen_arg,
+                deliver=deliver, link_counter=counter,
+                delivered_in_window=int(out["delivered_in_window"][i]),
+                in_flight=int(out["in_flight"][i]))
+            if replaying:
+                attach_replay(stats, wls[i],
+                              out["phase_done"][i, :wls[i].num_phases])
+            if tr.request is not None:
+                # Serving metrics need request ids in the engine's packet
+                # order.  Recompute _pack_traffic's permutation (a stable
+                # lexsort over identical inputs — bit-identical to the one
+                # the packing used) host-side; the compiled program never
+                # sees the request array.
+                req = np.asarray(tr.request, dtype=np.int64)
+                src64 = tr.src.astype(np.int64)
+                gen64 = tr.gen.astype(np.int64)
+                sort_key = src64 * (gen64.max(initial=0) + 1) + gen64
+                if not np.all(sort_key[1:] >= sort_key[:-1]):
+                    req = req[np.lexsort((tr.gen, tr.src))]
+                attach_serving(stats, req,
+                               packed[i]["gen"][:m].astype(np.int64),
+                               deliver, slo=tr.slo)
+            stats.timing = timing
+            if trace_cfg is not None:
+                # Slice copy i's columns out of the flat ring buffers; block
+                # bounds come back to local pid space by removing the copy's
+                # packet-id base.
+                injected = out["tr_inj"][tr_valid][:, i * n:(i + 1) * n
+                                                   ].astype(np.int64)
+                backlog = derive_backlog(
+                    tr_cycles, injected,
+                    packed[i]["gen"][:m].astype(np.int64),
+                    packed[i]["blk_start"].astype(np.int64) - int(bases[i]),
+                    packed[i]["blk_end"].astype(np.int64) - int(bases[i]),
+                    phase_done=(out["phase_done"][i, :wls[i].num_phases]
+                                if replaying else None))
+                stats.trace = Trace(
+                    stride=trace_cfg.stride, cycles=tr_cycles,
+                    link_load=out["tr_link"][tr_valid][
+                        :, i * n_links:(i + 1) * n_links],
+                    queue_occ=out["tr_occ"][tr_valid][:, i * n:(i + 1) * n],
+                    injected=injected,
+                    delivered=out["tr_del"][tr_valid][:, i],
+                    backlog=backlog,
+                    meta={"topology": topo.name, "policy": policy.name,
+                          "backend": "jax", "num_switches": n,
+                          "num_ports": topo.num_ports, "terminals": terminals,
+                          "load": load, "seed": seed})
+            results.append(stats)
+    timing.update(recorded_spans())
     return [results[li * len(seeds):(li + 1) * len(seeds)]
             for li in range(len(loads))]
 

@@ -166,7 +166,8 @@ def _show_results(spec_path: str, store_arg: str | None) -> None:
 
 
 def _show_trace(spec_path: str, specs, store_arg: str | None) -> None:
-    """The ``show --trace`` tail: stored provenance + compile-tax totals."""
+    """The ``show --trace`` tail: stored provenance, compile-tax totals
+    and the host spans' seconds beside them."""
     store_path = store_arg if store_arg is not None \
         else _default_store(spec_path)
     store = JsonlStore(store_path)
@@ -193,6 +194,9 @@ def _show_trace(spec_path: str, specs, store_arg: str | None) -> None:
         print(f"    compile={timings.get('compile_s')}s{cached}"
               f" execute={timings.get('execute_s')}s"
               f" amortized={amortized:.6f}s/point")
+        spans = _span_text(timings)
+        if spans:
+            print(f"    host spans: {spans}")
     if not timed:
         print("  no records carry timings (store predates telemetry); "
               "re-run with --no-resume to refresh")
@@ -211,6 +215,16 @@ def _show_trace(spec_path: str, specs, store_arg: str | None) -> None:
             print(f"  {name}: {t['programs']} program(s), {t['points']} "
                   f"point(s), compile={t['compile_s']}s "
                   f"execute={t['execute_s']}s")
+            spans = _span_text(t)
+            if spans:
+                print(f"    host spans: {spans}")
+
+
+def _span_text(timings: dict) -> str:
+    """The host spans' seconds of a timing dict, in the order recorded
+    (``sweep.traffic=0.0012s ...``)."""
+    return " ".join(f"{k[:-2]}={v}s" for k, v in timings.items()
+                    if k.startswith(("sweep.", "study.")))
 
 
 def cmd_trace(args) -> int:

@@ -28,6 +28,7 @@ import json
 from dataclasses import dataclass
 from typing import Sequence
 
+from ..obs.telemetry import recorded_spans, span, span_record
 from .spec import ExperimentSpec, load_specs
 from .store import JsonlStore, Result
 
@@ -246,7 +247,10 @@ class StudyResult:
         grid points, so the sum here counts each program once, not once
         per point.  ``compile_s``/``execute_s`` are program totals;
         ``points`` is the grid points they covered (restored points
-        contribute their stored provenance timings, if any).
+        contribute their stored provenance timings, if any).  The host
+        spans' seconds (``sweep.traffic_s``, ``study.records_s``, ...;
+        :func:`repro.obs.telemetry.span`) are totals the same way, for
+        the spans the timings recorded.
         """
         out: dict[str, dict] = {}
         for exp in self.experiments:
@@ -277,6 +281,11 @@ class StudyResult:
                     "execute_s": round(sum(t.get("execute_s", 0.0)
                                            for t in seen), 6),
                 }
+                spans = sorted({k for t in seen for k in t
+                                if k.startswith(("sweep.", "study."))})
+                out[exp.name].update(
+                    (k, round(sum(t.get(k, 0.0) for t in seen), 6))
+                    for k in spans)
         return out
 
     def table(self) -> str:
@@ -524,10 +533,12 @@ class Study:
         out["capacity"] = round(good, 6)
         return out
 
+    @span_record()
     def _run_jax(self, exp: ExperimentSpec,
                  missing: Sequence[tuple[float, int]]) -> list[Result]:
         from repro.sim import xengine
-        topo, tf = self._resolve(exp)
+        with span("study.resolve"):
+            topo, tf = self._resolve(exp)
         sweep = exp.sweep
         kw = dict(terminals=exp.terminals, cycles=sweep.cycles,
                   warmup=sweep.warmup, **dict(exp.engine))
@@ -559,10 +570,19 @@ class Study:
                 list(range(len(pts))), seeds=(pseudo_seed,), **kw)
             flat = [(load, seed, grid[i][0])
                     for i, (load, seed) in enumerate(pts)]
-        return [Result.from_stats(stats, key=exp.key(load, seed),
-                                  experiment=exp.name, load=load, seed=seed,
-                                  backend="jax", spec_digest=exp.digest())
-                for load, seed, stats in flat]
+        with span("study.records"):
+            out = [Result.from_stats(stats, key=exp.key(load, seed),
+                                     experiment=exp.name, load=load,
+                                     seed=seed, backend="jax",
+                                     spec_digest=exp.digest())
+                   for load, seed, stats in flat]
+        # study.records closes after the provenance copies were made: its
+        # seconds go into the shared timing dict and into every copy.
+        spans = recorded_spans()
+        flat[0][2].timing.update(spans)
+        for r in out:
+            r.provenance["timings"].update(spans)
+        return out
 
     def _run_flow(self, exp: ExperimentSpec,
                   missing: Sequence[tuple[float, int]]) -> list[Result]:
