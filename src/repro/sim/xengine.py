@@ -18,10 +18,14 @@ except the delivery-timestamp record is formulated as a gather, select,
 or axis reduction:
 
 * **Queues.** Every (switch, input-port, VC) FIFO is a lane; ``occ > 0``
-  masks the active ones.  The packet attributes that evolve in flight
-  (itinerary ``mid``, routing ``phase``, ``hops``) ride *inside* the
-  ring buffers as one packed word per slot, pushed and popped with the
-  packet id; a packet's location is implicit in the queue holding it.
+  masks the active ones.  The packet attributes routing reads in flight
+  (destination ``dst``, itinerary ``mid``, routing ``phase``, ``hops``)
+  ride *inside* the ring buffers as one packed word per slot, pushed and
+  popped with the packet id, so the queue heads route without gathering
+  from the packet table; a packet's location is implicit in the queue
+  holding it.  The word's field widths follow the fabric
+  (:func:`_word_layout`); a fabric too large for ``dst`` to fit leaves it
+  out and gathers each head's destination by packet id instead.
 * **Routing.** The table-free minimal route is evaluated once per
   topology into a dense ``(N, N)`` next-port table
   (:meth:`SimTopology.minimal_port_table`); in-step routing is a gather.
@@ -86,10 +90,12 @@ _INT32_MAX = np.iinfo(np.int32).max
 #: Sentinel generation cycle for padded packet slots: larger than any
 #: simulated cycle, so a padded slot never becomes an injection candidate.
 _PAD_GEN = _INT32_MAX
-#: Hop counts saturate at this value inside the packed attribute word
-#: (mid << 8 | phase << 7 | hops); hops only feed the VC-class clamp
-#: ``min(hops, num_vcs - 1)``, so saturation is lossless for V <= 128.
+#: Hop counts saturate at or below this value inside the packed attribute
+#: word (see :func:`_word_layout`); hops only feed the VC-class clamp
+#: ``min(hops - 1, num_vcs - 1)``, so saturation is lossless for V <= 128.
 _MAX_HOPS = 127
+#: Bits of the packed attribute word; the int32 sign bit stays clear.
+_WORD_BITS = 31
 
 
 #: Above this many (horizon x queue-lane) entries the per-cycle ejection
@@ -206,6 +212,7 @@ class _State(NamedTuple):
     Exactly one of the two is non-trivial per compile.
     """
     buf: jax.Array               # (Q, cap, 2) ring buffers: pid, attr word
+    #                              (dst | mid | phase | hops; _word_layout)
     head: jax.Array              # (Q,)
     occ: jax.Array               # (Q,)
     deliver: jax.Array           # (M,) delivery cycle, -1 = in flight
@@ -228,8 +235,46 @@ class _State(NamedTuple):
     tr_del: jax.Array            # (S, B) cumulative deliveries per copy
 
 
-def _pack_attr(mid, phase, hops):
-    return (mid << 8) | (phase << 7) | jnp.minimum(hops, _MAX_HOPS)
+def _word_layout(n: int, vcs: int) -> tuple[int, int]:
+    """``(hop_bits, id_bits)``: the packed attribute word's field widths
+    for a fabric of ``n`` switches and ``vcs`` VCs.
+
+    The word is ``dst | mid | phase | hops``, high bits first: ``dst``
+    and ``mid`` take ``id_bits = bits(n - 1)`` each, ``phase`` one bit,
+    and hops saturate at ``2**hop_bits - 1``, which is at least
+    ``min(vcs, _MAX_HOPS)`` — so the VC class ``min(hops - 1, vcs - 1)``
+    reads exactly as unsaturated hops give it.  When the fields overflow
+    :data:`_WORD_BITS`, ``id_bits`` is 0: the word is ``mid << 8 | phase
+    << 7 | hops`` with no ``dst`` field, and the step gathers each queue
+    head's destination from the packet table by packet id."""
+    id_bits = max(int(n - 1).bit_length(), 1)
+    hop_bits = min(int(vcs).bit_length(), _MAX_HOPS.bit_length())
+    if 2 * id_bits + 1 + hop_bits <= _WORD_BITS:
+        return hop_bits, id_bits
+    return _MAX_HOPS.bit_length(), 0
+
+
+def _pack_attr(layout: tuple[int, int], dst, mid, phase, hops):
+    """Packed attribute words (see :func:`_word_layout`); ``dst`` is
+    dropped when the layout has no field for it."""
+    hop_bits, id_bits = layout
+    word = ((mid << (hop_bits + 1)) | (phase << hop_bits)
+            | jnp.minimum(hops, (1 << hop_bits) - 1))
+    if id_bits:
+        word = word | (dst << (hop_bits + 1 + id_bits))
+    return word
+
+
+def _unpack_attr(layout: tuple[int, int], word):
+    """``(dst, mid, phase, hops)`` of packed words; ``dst`` is None when
+    the layout has no field for it."""
+    hop_bits, id_bits = layout
+    hops = word & ((1 << hop_bits) - 1)
+    phase = (word >> hop_bits) & 1
+    mid = word >> (hop_bits + 1)
+    if not id_bits:
+        return None, mid, phase, hops
+    return (mid >> id_bits, mid & ((1 << id_bits) - 1), phase, hops)
 
 
 def _resolve_policy(policy) -> RoutingPolicy:
@@ -317,6 +362,9 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         key_dtype, sent = _I32, _INT32_MAX
         rand_bits = min(30 - x_bits, 16)
     src, dst, gen = pkt["src"], pkt["dst"], pkt["gen"]
+    layout = _word_layout(n, v)
+    hop_bits = layout[0]
+    hop_mask = (1 << hop_bits) - 1
     c = state.cycle
     # Every op of the step is traced under exactly one named scope (rng,
     # eject, route, arbitrate, move, sample), so a profile can give each
@@ -361,11 +409,10 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         head_slot = state.head % cap
         h_pair = state.buf[lanes, head_slot]        # (Q, 2): pid, attr
         pid = jnp.where(valid, h_pair[:, 0], 0)
-        h_attr = h_pair[:, 1]
-        h_mid = h_attr >> 8
-        h_phase = (h_attr >> 7) & 1
-        h_hops = h_attr & _MAX_HOPS
-        done = valid & (tables.sw_local == dst[pid]) & (h_phase == 1)
+        h_dst, h_mid, h_phase, h_hops = _unpack_attr(layout, h_pair[:, 1])
+        if h_dst is None:                 # no dst field: gather by packet id
+            h_dst = dst[pid]
+        done = valid & (tables.sw_local == h_dst) & (h_phase == 1)
 
         # 1. ejection: up to eject_bw random winners per switch ---------------
         # Winners are the eject_bw smallest unique (randbits, lane) keys among
@@ -433,7 +480,7 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         # 2. transit requests -------------------------------------------------
         transit = valid & ~done
         sw_q = tables.sw_local
-        tgt = jnp.where(h_phase == 1, dst[pid], h_mid)
+        tgt = jnp.where(h_phase == 1, h_dst, h_mid)
         safe_tgt = jnp.where(transit & (tgt != sw_q), tgt, (sw_q + 1) % n)
         t_port = tables.port_table[sw_q, safe_tgt]
 
@@ -450,10 +497,11 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         else:
             inj_valid &= gen[ip] <= c
 
-        i_mid, i_phase = dst[ip], jnp.ones(nt_flat, _I32)
+        i_dst = dst[ip]
+        i_mid, i_phase = i_dst, jnp.ones(nt_flat, _I32)
         if spec.policy != "minimal" and n >= 3:
             # Uniform intermediate avoiding {src, dst} (shift-remap).
-            s_i, d_i = src[ip], dst[ip]
+            s_i, d_i = src[ip], i_dst
             lo = jnp.minimum(s_i, d_i)
             hi = jnp.maximum(s_i, d_i)
             r = ((term_bits & np.uint32(0x3FFF)) % np.uint32(n - 2)
@@ -486,7 +534,7 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
                 i_mid = jnp.where(detour, r, d_i)
                 i_phase = jnp.where(detour, 0, 1).astype(_I32)
 
-        i_tgt = jnp.where(i_phase == 1, dst[ip], i_mid)
+        i_tgt = jnp.where(i_phase == 1, i_dst, i_mid)
         i_src = src[ip]
         i_tgt = jnp.where(i_tgt != i_src, i_tgt, (i_src + 1) % n)
         i_port = tables.port_table[i_src, i_tgt]
@@ -494,8 +542,8 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
     with jax.named_scope("arbitrate"):
         # 4. link arbitration with credit check -------------------------------
         # Contender block per switch: its pv queue heads then its t terminals.
-        # The attribute word carries (mid, phase, hops-after-this-hop), so the
-        # requested VC class is derived from it: min(hops - 1, V-1).
+        # The attribute word carries (dst, mid, phase, hops-after-this-hop),
+        # so the requested VC class is derived from it: min(hops - 1, V-1).
         act = jnp.concatenate([transit.reshape(blocks, pv),
                                inj_valid.reshape(blocks, t)], axis=1)
         port_x = jnp.concatenate([t_port.reshape(blocks, pv),
@@ -503,10 +551,11 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         pid_x = jnp.concatenate([pid.reshape(blocks, pv),
                                  ip.reshape(blocks, t)], axis=1)
         attr_x = jnp.concatenate([
-            _pack_attr(h_mid, h_phase, h_hops + 1).reshape(blocks, pv),
-            _pack_attr(i_mid, i_phase, jnp.ones(nt_flat, _I32)
-                       ).reshape(blocks, t)], axis=1)
-        vc_x = jnp.minimum((attr_x & _MAX_HOPS) - 1, v - 1)
+            _pack_attr(layout, h_dst, h_mid, h_phase, h_hops + 1
+                       ).reshape(blocks, pv),
+            _pack_attr(layout, i_dst, i_mid, i_phase,
+                       jnp.ones(nt_flat, _I32)).reshape(blocks, t)], axis=1)
+        vc_x = jnp.minimum((attr_x & hop_mask) - 1, v - 1)
 
         # Credit check against the downstream (port, VC) queue of each
         # contender's requested link.  The downstream (switch, input-port) of
@@ -573,13 +622,13 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
         pair_x = jnp.stack([pid_x, attr_x], axis=-1).reshape(blocks * x, 2)
         pair_w = pair_x[recv_x]                     # (Q, 2): pid, attr
         pid_w, attr_w = pair_w[:, 0], pair_w[:, 1]
-        vc_w = jnp.minimum((attr_w & _MAX_HOPS) - 1, v - 1)
+        vc_w = jnp.minimum((attr_w & hop_mask) - 1, v - 1)
         recv = tables.wired_q & (mv != sent) & (vc_w == tables.vc_of_lane)
         # Phase flips on arrival at the Valiant intermediate — which, seen
         # from the receiving queue, is simply its own switch.
-        attr_w = jnp.where(((attr_w & (1 << 7)) == 0)
-                           & ((attr_w >> 8) == tables.sw_local),
-                           attr_w | (1 << 7), attr_w)
+        _, w_mid, w_phase, _ = _unpack_attr(layout, attr_w)
+        attr_w = jnp.where((w_phase == 0) & (w_mid == tables.sw_local),
+                           attr_w | (1 << hop_bits), attr_w)
 
         slot = (head + occ) % cap
         onehot = (jnp.arange(cap, dtype=_I32)[None, :] == slot[:, None]
@@ -638,24 +687,13 @@ def _step(spec: XSpec, tables: _Tables, pkt: dict, base_key: jax.Array,
                       tr_occ=tr_occ, tr_inj=tr_inj, tr_del=tr_del)
 
 
-def _run_loop(spec: XSpec, tables: _Tables, pkt: dict, key: jax.Array,
-              warmup: jax.Array) -> dict:
-    """One device's whole run: state init, the cycle loop, output dict.
-
-    Shapes derive from the *local* packet/block arrays, so the same body
-    serves the single-device jit (:data:`_run_flat`, all copies in one
-    flat state) and each shard of :func:`_sharded_runner` (a contiguous
-    block of copies per device).  The static ``spec.horizon``/``cutoff``
-    only size allocations and trip counts; the *measured* bounds are the
-    runtime ``pkt["h_eff"]``/``pkt["cutoff_eff"]`` scalars, so a
-    bucket-padded program computes exactly what the exact-shape program
-    would (see :func:`sweep`).
-    """
+def _init_state(spec: XSpec, pkt: dict) -> _State:
+    """The loop carry before cycle 0 for the copies ``pkt`` holds."""
     n, p, v = spec.n, spec.ports, spec.vcs
     b = pkt["blk_start"].shape[0] // n
     bq = b * n * p * v
     m_flat = pkt["src"].shape[0]
-    state = _State(
+    return _State(
         buf=jnp.full((bq, spec.cap, 2), -1, _I32),
         head=jnp.zeros(bq, _I16),
         occ=jnp.zeros(bq, _I16),
@@ -681,6 +719,24 @@ def _run_loop(spec: XSpec, tables: _Tables, pkt: dict, key: jax.Array,
         tr_del=jnp.zeros((spec.trace_samples, b)
                          if spec.trace_stride else (1, 1), _I32),
     )
+
+
+def _run_loop(spec: XSpec, tables: _Tables, pkt: dict, key: jax.Array,
+              warmup: jax.Array) -> dict:
+    """One device's whole run: state init, the cycle loop, output dict.
+
+    Shapes derive from the *local* packet/block arrays, so the same body
+    serves the single-device jit (:data:`_run_flat`, all copies in one
+    flat state) and each shard of :func:`_sharded_runner` (a contiguous
+    block of copies per device).  The static ``spec.horizon``/``cutoff``
+    only size allocations and trip counts; the *measured* bounds are the
+    runtime ``pkt["h_eff"]``/``pkt["cutoff_eff"]`` scalars, so a
+    bucket-padded program computes exactly what the exact-shape program
+    would (see :func:`sweep`).
+    """
+    n = spec.n
+    b = pkt["blk_start"].shape[0] // n
+    state = _init_state(spec, pkt)
 
     def body(st: _State):
         return _step(spec, tables, pkt, key, warmup, st)
@@ -717,8 +773,7 @@ def _run_loop(spec: XSpec, tables: _Tables, pkt: dict, key: jax.Array,
         "delivered_in_window": final.delivered_win,
         "phase_done": final.phase_done,
         "cycle": final.cycle,
-        "in_flight": final.occ.reshape(b, n * p * v).sum(axis=1,
-                                                         dtype=_I32),
+        "in_flight": final.occ.reshape(b, -1).sum(axis=1, dtype=_I32),
     }
     if spec.trace_stride:
         out.update(tr_cycle=final.tr_cycle, tr_link=final.tr_link,
@@ -1162,6 +1217,7 @@ def sweep(topo: SimTopology, policy, traffic_factory: Callable,
                           "num_ports": topo.num_ports, "terminals": terminals,
                           "load": load, "seed": seed})
             results.append(stats)
+    timing["dst_in_word"] = int(_word_layout(n, num_vcs)[1] > 0)
     timing.update(recorded_spans())
     return [results[li * len(seeds):(li + 1) * len(seeds)]
             for li in range(len(loads))]

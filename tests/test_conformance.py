@@ -18,7 +18,10 @@ persistent-compile-cache + shape-bucketing + sharding rebuild rests on:
   host devices — the ``shard_map``-sharded program.  Bit-identical means
   every :class:`RunStats` field, not statistics within tolerance: the
   per-copy RNG keying guarantees padding and sharding never perturb a
-  single arbitration draw.
+  single arbitration draw.  The program that carries each packet's
+  destination in its queue's packed word and the one that gathers it
+  from the packet table (the layout of fabrics too large for the field)
+  agree bit for bit too.
 """
 import dataclasses
 import os
@@ -26,6 +29,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -33,6 +37,7 @@ from hypothesis import given, settings, strategies as st
 import repro
 import repro.fabric.mirror  # noqa: F401  (registers the mirror instance)
 from repro import sim
+from repro.core.dragonfly import DragonflyConfig
 from repro.fabric import make_fabric
 from repro.faults import FailureSpec
 from repro.sim import xengine
@@ -182,6 +187,75 @@ def test_bucketing_invariance_property(points, cycles):
 
 
 # ---------------------------------------------------------------------------
+# Programs: destination in the packed word == destination gathered by
+# packet id, bit for bit.
+# ---------------------------------------------------------------------------
+
+_REAL_WORD_LAYOUT = xengine._word_layout
+
+
+def _layout_without_dst(n, vcs):
+    """The word of a fabric too large for a ``dst`` field, at ``vcs``."""
+    return _REAL_WORD_LAYOUT(1 << 16, vcs)
+
+
+def _dragonfly_grid(policy):
+    topo = sim.dragonfly_topology(DragonflyConfig(
+        group_size=4, terminals_per_switch=2, global_ports_per_switch=2,
+        num_groups=6))
+
+    def tf(load, seed):
+        return sim.uniform(topo.num_switches, offered=load, cycles=96,
+                           terminals=2, seed=seed)
+
+    grid = xengine.sweep(topo, policy, tf, [0.3, 0.9], seeds=(0, 1),
+                         terminals=2, cycles=96, warmup=24)
+    return [s for row in grid for s in row]
+
+
+_WORD_SCENARIOS = {
+    "minimal": lambda: _dragonfly_grid("minimal"),
+    "valiant": lambda: _dragonfly_grid("valiant"),
+    "adaptive": lambda: _dragonfly_grid("adaptive"),
+    "degraded": lambda: [sim.simulate(
+        sim.cin_topology("circle", 9), sim.make_policy("adaptive"),
+        sim.uniform(9, offered=0.6, cycles=100, terminals=4, seed=3),
+        terminals=4, cycles=100, seed=2, backend="jax",
+        failures=FailureSpec(link_fraction=0.08, seed=3))],
+    "replay": lambda: [make_fabric("xor", 8).replay(
+        "all_to_all", message_size=2, backend="jax")],
+    "serving": lambda: [sim.simulate(
+        sim.cin_topology("circle", 9), sim.MinimalPolicy(),
+        _drained_scenario("serving", "circle", 9)[0], terminals=4,
+        drain=True, seed=5, backend="jax")],
+}
+
+
+@pytest.mark.parametrize("scenario", list(_WORD_SCENARIOS))
+def test_dst_in_word_bit_identical_to_gathered_dst(scenario, tmp_path,
+                                                   monkeypatch):
+    run = _WORD_SCENARIOS[scenario]
+    in_word = run()
+    assert all(s.timing["dst_in_word"] == 1 for s in in_word)
+    # The program cache keys on the jitted function and the spec, which
+    # the word layout does not change: trace the step again through a
+    # jit of its own, with a disk layer of its own.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(xengine, "_word_layout", _layout_without_dst)
+    monkeypatch.setattr(xengine, "_run_flat", jax.jit(
+        lambda spec, *args: xengine._run_loop(spec, *args),
+        static_argnums=0))
+    gathered = run()
+    monkeypatch.undo()
+    assert all(s.timing["dst_in_word"] == 0 for s in gathered)
+    assert gathered[0].timing["compile_cached"] is False
+    assert len(in_word) == len(gathered)
+    for a, b in zip(in_word, gathered):
+        _assert_bit_identical(a, b)
+    assert sum(s.packets_delivered for s in in_word) > 0
+
+
+# ---------------------------------------------------------------------------
 # Programs: disk-restored executable == freshly compiled, bit for bit.
 # ---------------------------------------------------------------------------
 
@@ -263,7 +337,6 @@ def test_devices_validation():
     with pytest.raises(ValueError, match="devices"):
         xengine.simulate_jax(topo, sim.MinimalPolicy(), tr, terminals=4,
                              devices=0)
-    import jax
     too_many = jax.local_device_count() + 1
     with pytest.raises(ValueError, match="visible"):
         xengine.simulate_jax(topo, sim.MinimalPolicy(), tr, terminals=4,

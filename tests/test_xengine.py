@@ -12,8 +12,10 @@ Two tiers of agreement:
   tolerances (the engines draw arbitration tie-breaks from different RNG
   streams).
 """
+import jax
 import numpy as np
 import pytest
+from jax.extend.core import Var
 
 import repro.fabric.mirror  # noqa: F401  (registers the mirror instance)
 from repro import sim
@@ -286,3 +288,127 @@ def test_engine_pressure_updates_every_cycle_when_blocked():
     # pressure must keep moving cycle-over-cycle (no frozen stale reads)
     diffs = [np.abs(a - b).sum() for a, b in zip(pressures, pressures[1:])]
     assert np.count_nonzero(diffs) >= len(diffs) // 2
+
+
+# ---------------------------------------------------------------------------
+# The queue's packed attribute word: layout and what the step gathers.
+# ---------------------------------------------------------------------------
+
+def _smallest_n_without_dst(vcs):
+    """The fewest switches whose packed word has no room for ``dst``."""
+    return next(n for n in (2 ** k + 1 for k in range(31))
+                if xengine._word_layout(n, vcs)[1] == 0)
+
+
+def test_word_layout_boundaries():
+    # dragonfly-2064 at its 3 VCs: 12 + 12 + 1 + 2 bits.
+    assert xengine._word_layout(2064, 3) == (2, 12)
+    assert xengine._word_layout(4096, 3) == (2, 12)
+    assert xengine._word_layout(96, 4) == (3, 7)        # hyperx-12x8
+    assert _smallest_n_without_dst(3) == 2 ** 14 + 1
+    for vcs in range(1, 129):
+        n_fb = _smallest_n_without_dst(vcs)
+        hop_bits, id_bits = xengine._word_layout(n_fb - 1, vcs)
+        assert 2 * id_bits + 1 + hop_bits <= 31
+        assert (1 << hop_bits) - 1 >= min(vcs, xengine._MAX_HOPS)
+        assert xengine._word_layout(n_fb, vcs) == (7, 0)
+
+
+@pytest.mark.parametrize("vcs", range(1, 9))
+def test_word_round_trip_and_vc_class(vcs):
+    n_fb = _smallest_n_without_dst(vcs)
+    hops = np.arange(201, dtype=np.int32)
+    today = np.minimum(np.minimum(hops, 127) - 1, vcs - 1)
+    for n in (2064, 4096, n_fb - 1, n_fb):
+        layout = xengine._word_layout(n, vcs)
+        # n - 1 sets the top bit of a bits(n - 1)-wide field.
+        ids = np.array([0, 1, n // 2, n - 1], np.int32)
+        d, m, ph, h = (a.ravel() for a in np.meshgrid(
+            ids, ids, np.array([0, 1], np.int32), hops, indexing="ij"))
+        word = np.asarray(xengine._pack_attr(layout, d, m, ph, h))
+        assert (word >= 0).all()
+        dst, mid, phase, h_out = (None if a is None else np.asarray(a)
+                                  for a in xengine._unpack_attr(layout, word))
+        if layout[1]:
+            assert np.array_equal(dst, d)
+        else:
+            assert dst is None and n == n_fb
+        assert np.array_equal(mid, m)
+        assert np.array_equal(phase, ph)
+        vc = np.minimum(h_out - 1, vcs - 1).reshape(-1, hops.size)
+        assert (vc == today).all()
+        # The step's own progression: inject at 1 hop, repack +1 a hop.
+        w = xengine._pack_attr(layout, ids, ids, ids % 2,
+                               np.ones_like(ids))
+        for true_hops in range(1, 201):
+            _, _, _, h_now = xengine._unpack_attr(layout, w)
+            assert (np.minimum(np.asarray(h_now) - 1, vcs - 1)
+                    == min(min(true_hops, 127) - 1, vcs - 1)).all()
+            w = xengine._pack_attr(layout, ids, ids, ids % 2, h_now + 1)
+
+
+def _dst_gather_lengths(jaxpr, tracked):
+    """Index counts of every gather from a var in ``tracked``, following
+    the vars into nested jaxprs by position."""
+    lengths = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather" and eqn.invars[0] in tracked:
+            lengths.append(eqn.invars[1].aval.shape[0])
+        inner = eqn.params.get("jaxpr")
+        if inner is not None:
+            inner = getattr(inner, "jaxpr", inner)
+            sub = {iv for ov, iv in zip(eqn.invars, inner.invars)
+                   if isinstance(ov, Var) and ov in tracked}
+            if sub:
+                lengths += _dst_gather_lengths(inner, sub)
+    return lengths
+
+
+@pytest.mark.parametrize("policy", ["minimal", "adaptive"])
+@pytest.mark.parametrize("dst_in_word", [1, 0])
+def test_step_gathers_dst_only_on_terminal_lanes(policy, dst_in_word,
+                                                 monkeypatch):
+    """With the destination in the packed word, the step reads
+    ``pkt["dst"]`` only at injection (NT terminal lanes), never per queue
+    lane (Q); the program without the field still shows the (Q,) gather,
+    which is what this check would find if it came back."""
+    topo = sim.hyperx_topology(HyperXConfig(dims=(4, 3), terminals=2,
+                                            instance="circle"))
+    if not dst_in_word:
+        real = xengine._word_layout
+        monkeypatch.setattr(xengine, "_word_layout",
+                            lambda n, vcs: real(1 << 16, vcs))
+    captured = {}
+
+    class _Captured(Exception):
+        pass
+
+    def capture(fn, spec, *args, **kw):
+        captured.update(spec=spec, args=args)
+        raise _Captured
+
+    monkeypatch.setattr(xengine, "timed_compiled", capture)
+    with pytest.raises(_Captured):
+        xengine.sweep(topo, policy,
+                      lambda load, seed: sim.uniform(12, offered=load,
+                                                     cycles=40, terminals=2,
+                                                     seed=seed),
+                      [0.3, 0.6], seeds=(0,), terminals=2, cycles=40)
+    spec, (tables, pkt, key, warmup) = captured["spec"], captured["args"]
+    rest = {k: a for k, a in pkt.items() if k != "dst"}
+
+    def step(dst, rest, state):
+        return xengine._step(spec, tables, dict(rest, dst=dst), key,
+                             warmup, state)
+
+    closed = jax.make_jaxpr(step)(pkt["dst"], rest,
+                                  xengine._init_state(spec, pkt))
+    lengths = _dst_gather_lengths(closed.jaxpr, {closed.jaxpr.invars[0]})
+    b = pkt["blk_start"].shape[0] // spec.n
+    nt = b * spec.n * spec.terminals
+    q = b * spec.n * spec.ports * spec.vcs
+    assert nt in lengths
+    if dst_in_word:
+        assert set(lengths) == {nt}
+    else:
+        assert q in lengths
