@@ -7,6 +7,12 @@ agree only in distribution, so its numbers are relative gaps, worst over
 the points compared; a collective replay is deterministic (every phase a
 contention-free 1-factor), so its numbers are exact differences.
 
+The reference's packets come from the builder the traffic file names
+(``"reference": "<name>"``): ``bench/reference/mixes/<name>.py``, whose
+``packets(fabric, traffic, terminals, load, seed)`` takes the
+configuration's ``fabric`` entry and the whole traffic file.  A new mix
+brings its own builder; nothing here knows a pattern or a fabric.
+
 * ``generated``   |packets generated - reference|, worst point (exact)
 * ``accepted``    |accepted - ref| / ref, worst point
 * ``latency``     |mean latency - ref| / ref, worst point
@@ -20,8 +26,9 @@ from __future__ import annotations
 
 import numpy as np
 
+import harness
 from reference import fabric as ref_fabric
-from reference import netsim, traffic as ref_traffic
+from reference import netsim
 
 
 def _rel(a: float, b: float) -> float:
@@ -36,27 +43,19 @@ class Reference:
         self.traffic = traffic
         self.fabric = ref_fabric.build(config["fabric"])
         self.control = control
+        if "reference" not in traffic:
+            raise harness.Refused("the traffic file names no reference "
+                                  "builder (its \"reference\" key)")
+        self.mix = harness.load_module("reference/mixes",
+                                       traffic["reference"])
 
     @property
     def replay(self) -> bool:
         return self.traffic["traffic"]["pattern"] == "workload"
 
     def packets(self, load: float, seed: int):
-        pattern = self.traffic["traffic"]
-        if pattern["pattern"] == "uniform":
-            return ref_traffic.uniform(
-                self.fabric.num_switches, offered=load,
-                cycles=self.traffic["cycles"],
-                terminals=self.config["terminals"], seed=seed)
-        if pattern["pattern"] == "workload" and \
-                self.config["fabric"]["kind"] == "hyperx":
-            prm = pattern["params"]
-            if prm.get("collective") != "all_to_all":
-                raise ValueError(f"no reference replay for {prm}")
-            return ref_traffic.a2a_replay(
-                self.config["fabric"]["params"]["dims"],
-                int(prm["message_size"]))
-        raise ValueError(f"no reference traffic for {pattern}")
+        return self.mix.packets(self.config["fabric"], self.traffic,
+                                self.config["terminals"], load, seed)
 
     def simulate(self, load: float, seed: int, rng_seed: int) -> dict:
         routing = self.traffic["routing"]

@@ -10,6 +10,8 @@ configuration and a traffic mix.  Everything else is found by name:
   ``bench/drivers/<driver>.py``, which runs the cell;
 * ``bench/limits/<cell>.json`` -- the limits of the numbers that decide
   ``correct``, and the control they were set against;
+* ``bench/reference/mixes/<reference>.py`` -- the reference's packets of
+  a simulator mix, named by the traffic file's ``reference`` key;
 * ``bench/metrics/<metric>.py`` -- one reader per per-layer metric;
 * ``bench/peaks.json`` -- the chip's peaks, keyed by ``device_kind``.
 """
@@ -83,12 +85,13 @@ def make_cell(name: str, config_file: str, traffic: str, chips: int,
 
 
 def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py`` as a module (names may hold dots)."""
+    """``bench/<kind>/<name>.py`` as a module (``kind`` may be a nested
+    directory, names may hold dots)."""
     path = os.path.join(BENCH, kind, name + ".py")
     if not os.path.exists(path):
-        raise Refused(f"no {kind[:-1]} file {path}")
+        raise Refused(f"no {kind} module {name!r}: {path}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{name}".replace("/", "_").replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod

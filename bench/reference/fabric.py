@@ -155,6 +155,16 @@ def dragonfly(group_size: int, global_ports: int, num_groups: int) -> Fabric:
     return Fabric(neighbor, rev, table.reshape(n, n), 3)
 
 
+def num_switches(fabric: dict) -> int:
+    """The switch count of a configuration file's ``fabric`` entry."""
+    kind, prm = fabric["kind"], fabric["params"]
+    if kind == "dragonfly":
+        return int(prm["group_size"]) * int(prm["num_groups"])
+    if kind == "hyperx":
+        return int(np.prod(prm["dims"]))
+    raise ValueError(f"no reference fabric for kind {kind!r}")
+
+
 def build(fabric: dict) -> Fabric:
     """The fabric a configuration file's ``fabric`` entry names."""
     kind, prm = fabric["kind"], fabric["params"]

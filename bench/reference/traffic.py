@@ -7,7 +7,9 @@ draw is the generator's published contract, repeated here so that the
 reference never reads a packet the program made).  ``a2a_replay`` lists
 the phases of the dimension-order all-to-all on a HyperX of Circle
 CINs: innermost dimension first, one phase per 1-factor of that
-dimension, ``message_size`` packets per (switch, partner) pair.
+dimension, ``message_size`` packets per (switch, partner) pair.  The
+builders of ``reference/mixes/``, which the traffic files name, make a
+cell's packets from these.
 """
 from __future__ import annotations
 
@@ -25,13 +27,21 @@ class Packets(NamedTuple):
     phase_sizes: np.ndarray | None   # packets per phase (replays only)
 
 
-def uniform(n: int, *, offered: float, cycles: int, terminals: int,
-            seed: int) -> Packets:
-    rng = np.random.default_rng(seed)
-    counts = rng.poisson(offered * terminals, size=(n, cycles))
+def poisson_arrivals(rng, n: int, rate: float, cycles: int
+                     ) -> tuple[np.ndarray, np.ndarray]:
+    """(src, gen) of Poisson(rate) arrivals per switch and cycle, sorted
+    by source, then cycle: the first draw of every open-loop mix."""
+    counts = rng.poisson(rate, size=(n, cycles))
     src = np.repeat(np.arange(n), counts.sum(axis=1)).astype(np.int64)
     gen = np.repeat(np.tile(np.arange(cycles), n),
                     counts.reshape(-1)).astype(np.int64)
+    return src, gen
+
+
+def uniform(n: int, *, offered: float, cycles: int, terminals: int,
+            seed: int) -> Packets:
+    rng = np.random.default_rng(seed)
+    src, gen = poisson_arrivals(rng, n, offered * terminals, cycles)
     d = rng.integers(0, n - 1, size=src.size)
     dst = np.where(d >= src, d + 1, d)
     return Packets(src, dst, gen, None)
