@@ -452,7 +452,8 @@ def mask_workload(workload, topo):
         if keep.any():
             phases.append(Phase(tuple(int(v) for v in src[keep]),
                                 tuple(int(v) for v in dst[keep]),
-                                ph.messages))
+                                ph.messages if ph.uniform else
+                                tuple(ph.pair_messages()[keep].tolist())))
     if not dirty:
         return workload
     return Workload(f"{workload.name}+degraded", workload.num_switches,

@@ -341,12 +341,13 @@ def _stats_from_solution(sol: FlowSolution, *, policy: str, traffic: str,
 
 def replay_estimate(topo: SimTopology, workload
                     ) -> tuple[list[int], np.ndarray]:
-    """Per-phase completion bound: a phase of ``messages`` packets per
-    pair whose worst directed link carries ``k`` overlapping pair
-    routes serializes to ``messages * k`` cycles (the engine moves one
-    packet per link per cycle and phases are barriered, so stochastic
-    HOL losses don't apply — deterministic schedules drain their links
-    back-to-back).  Returns ``(phase_cycles, lifetime link loads)``.
+    """Per-phase completion bound: a phase serializes to the packets its
+    worst directed link carries — ``messages * k`` cycles when ``k``
+    pair routes of ``messages`` packets each overlap there (the engine
+    moves one packet per link per cycle and phases are barriered, so
+    stochastic HOL losses don't apply — deterministic schedules drain
+    their links back-to-back).  Per-pair counts weigh each route by its
+    pair's packets.  Returns ``(phase_cycles, lifetime link loads)``.
 
     This is exactly how the Dragonfly all-to-all's ~4.4x plateau
     arises: each global step funnels ``a`` pair routes over one global
@@ -357,15 +358,16 @@ def replay_estimate(topo: SimTopology, workload
     loads = np.zeros(L)
     phase_cycles: list[int] = []
     for ph in workload.phases:
-        link_ids, _ptr = trace_routes(topo, np.asarray(ph.src),
-                                      np.asarray(ph.dst))
+        link_ids, ptr = trace_routes(topo, np.asarray(ph.src),
+                                     np.asarray(ph.dst))
         if link_ids.size:
-            counts = np.bincount(link_ids, minlength=L)
-            k = int(counts.max())
-            loads += counts * int(ph.messages)
+            per_link = np.bincount(
+                link_ids, minlength=L,
+                weights=np.repeat(ph.pair_messages(), np.diff(ptr)))
+            loads += per_link
+            phase_cycles.append(int(per_link.max()))
         else:
-            k = 1
-        phase_cycles.append(int(ph.messages) * max(k, 1))
+            phase_cycles.append(int(ph.peak_messages))
     return phase_cycles, loads
 
 
@@ -384,8 +386,8 @@ def replay_stats(topo: SimTopology, policy: str, traffic, workload, *,
         _ids, ptr = trace_routes(topo, np.asarray(ph.src),
                                  np.asarray(ph.dst))
         lat_vals.append(np.diff(ptr) + 1)
-        lat_w.append(np.full(len(ph.src), float(ph.messages)))
-        packets += len(ph.src) * int(ph.messages)
+        lat_w.append(ph.pair_messages().astype(float))
+        packets += ph.num_packets
     lat = np.concatenate(lat_vals) if lat_vals else np.zeros(0, np.int64)
     w = np.concatenate(lat_w) if lat_w else np.zeros(0)
     total_w = float(w.sum())

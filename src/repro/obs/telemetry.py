@@ -47,7 +47,9 @@ of once-per-process:
   (``sweep.traffic``, ``sweep.pack``, ``sweep.tables``,
   ``sweep.transfer``, ``sweep.fetch``, ``sweep.stats``,
   ``study.resolve``, ``study.records``) and merge the record into the
-  grid's timing dict.
+  grid's timing dict; ``repro.sim.workloads.moe_dispatch_workload``
+  opens ``sweep.gate`` inside ``sweep.traffic``.  :func:`count` adds a
+  counter to the same record.
 
 * :func:`scope_maps` maps, for every program the memory cache holds,
   each optimized-HLO instruction to the ``jax.named_scope`` path it was
@@ -81,7 +83,7 @@ import numpy as np
 __all__ = ["timed_compiled", "provenance", "timing_dict", "cache_dir",
            "cache_stats", "reset_cache_stats", "clear_caches",
            "disk_cache_entries", "CACHE_FORMAT", "span", "span_record",
-           "recorded_spans", "scope_map", "scope_maps"]
+           "recorded_spans", "count", "scope_map", "scope_maps"]
 
 #: Bump when the on-disk entry layout changes: old entries become
 #: unreadable garbage to the new code, so the version participates in
@@ -309,6 +311,18 @@ def _fn_ident(fn) -> str:
     name = getattr(inner, "__qualname__",
                    getattr(inner, "__name__", repr(inner)))
     return f"{mod}.{name}"
+
+
+def count(name: str, value, *, largest: bool = False) -> None:
+    """A counter of the open :func:`span_record`, if any: ``value``
+    added to ``name``, or with ``largest`` the larger of the two kept.
+    Counters ride in the grid's timing dict beside the span seconds
+    (``moe_copies``, ``moe_pair_max_over_mean``)."""
+    if _RECORDS:
+        rec = _RECORDS[-1]
+        if name in rec:
+            value = max(rec[name], value) if largest else rec[name] + value
+        rec[name] = value
 
 
 @lru_cache(maxsize=1)

@@ -24,11 +24,17 @@ cycle at which each phase completed.
 
 The headline comparison is measured completion against the schedule
 algebra's contention-free lower bound (:attr:`Workload.ideal_cycles` =
-``sum of per-phase messages`` = ``num_steps * message_size`` for uniform
-messages): a phase that is a matching on its fabric meets the bound
-exactly; the Dragonfly global steps — ``group_size`` flows sharing one
-global link — exceed it by precisely the serialization the hierarchy
-trades for 1/a-sized payloads.
+the sum over phases of the busiest pair's messages = ``num_steps *
+message_size`` for uniform messages): a phase that is a matching on its
+fabric meets the bound exactly; the Dragonfly global steps —
+``group_size`` flows sharing one global link — exceed it by precisely
+the serialization the hierarchy trades for 1/a-sized payloads.
+
+A phase's ``messages`` is one count for every pair, or one count per
+pair: the uneven, redrawn-per-step exchange of a mixture-of-experts
+layer's expert-parallel dispatch and combine
+(:func:`moe_dispatch_workload`, DeepSeek-V3's node-limited gate on the
+rows of a HyperX) is a replay like any other.
 
 Entry points, lowest to highest level::
 
@@ -36,23 +42,29 @@ Entry points, lowest to highest level::
     w = collective_workload(fabric, "all_to_all", message_size=2)
     stats = replay(topo, "minimal", w)            # RunStats + replay fields
     stats = fabric.replay("all_to_all")           # one-call Fabric surface
+    w = moe_dispatch_workload(hyperx_fab, MoESpec(), seed=7)
 
 and declaratively, ``TrafficSpec("workload", {"collective": ...})`` runs
 replays through :mod:`repro.studies` (the bundled ``collective_replay``
-spec compares CIN-16 / HyperX-256 / Dragonfly-72).
+spec compares CIN-16 / HyperX-256 / Dragonfly-72), and
+``TrafficSpec("workload", {"moe": {...}})`` draws the MoE exchange anew
+from every grid point's seed (the bundled ``moe_dispatch`` spec).
 """
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Mapping, Sequence
 
 import numpy as np
 
+from ..obs.telemetry import count, span
 from .metrics import RunStats
 from .traffic import Traffic
 
-__all__ = ["Phase", "Workload", "collective_workload", "replay"]
+__all__ = ["Phase", "Workload", "collective_workload", "replay",
+           "MoESpec", "moe_dispatch_workload",
+           "noaux_tc"]
 
 
 @dataclass(frozen=True)
@@ -65,24 +77,61 @@ class Phase:
     anisoport ``cyclic`` baseline and hierarchical global steps are plain
     permutations/flows — but every pair must be a real move
     (``src != dst``).
+
+    ``messages`` is one count for every pair (an int), or a tuple with
+    pair ``i``'s count at ``messages[i]`` (uneven steps, such as a
+    dropless expert-parallel dispatch); every count is at least 1, so a
+    builder drops the pairs that send nothing.
     """
     src: tuple[int, ...]
     dst: tuple[int, ...]
-    messages: int = 1
+    messages: int | tuple[int, ...] = 1
 
     def __post_init__(self):
         if len(self.src) != len(self.dst):
             raise ValueError(f"phase src/dst length mismatch: "
                              f"{len(self.src)} != {len(self.dst)}")
-        if self.messages < 1:
-            raise ValueError(f"messages must be >= 1, got {self.messages}")
+        if isinstance(self.messages, (int, np.integer)):
+            if self.messages < 1:
+                raise ValueError(
+                    f"messages must be >= 1, got {self.messages}")
+        else:
+            per_pair = tuple(int(m) for m in self.messages)
+            if len(per_pair) != len(self.src):
+                raise ValueError(
+                    f"per-pair messages need one count per pair: "
+                    f"{len(per_pair)} counts for {len(self.src)} pairs")
+            if any(m < 1 for m in per_pair):
+                raise ValueError("per-pair messages must be >= 1 (drop "
+                                 "the pairs that send nothing)")
+            object.__setattr__(self, "messages", per_pair)
         if any(a == b for a, b in zip(self.src, self.dst)):
             raise ValueError("a phase pair must move between distinct "
                              "switches (drop idle devices instead)")
 
     @property
+    def uniform(self) -> bool:
+        """Whether every pair sends the same count (an int ``messages``)."""
+        return not isinstance(self.messages, tuple)
+
+    @property
     def num_packets(self) -> int:
-        return len(self.src) * self.messages
+        if self.uniform:
+            return len(self.src) * self.messages
+        return sum(self.messages)
+
+    @property
+    def peak_messages(self) -> int:
+        """The busiest pair's count: the cycles the phase needs at least
+        on that pair's first link (the int itself for uniform phases)."""
+        if self.uniform:
+            return self.messages
+        return max(self.messages, default=0)
+
+    def pair_messages(self) -> np.ndarray:
+        """Each pair's count, as an int64 array."""
+        return (np.full(len(self.src), self.messages, dtype=np.int64)
+                if self.uniform else np.asarray(self.messages, np.int64))
 
 
 @dataclass(frozen=True)
@@ -119,15 +168,16 @@ class Workload:
     def ideal_cycles(self) -> int:
         """Contention-free lower bound on completion, in cycles.
 
-        Each phase needs at least ``messages`` cycles of link time on
-        its busiest link (one packet per directed link per cycle), and
-        phases are barrier-serialized, so completion cannot beat the sum
-        — ``num_steps * message_size`` for uniform messages.  The bound
-        is *met with equality* when every phase is contention-free on
-        the fabric (one flow per directed link, e.g. 1-factor steps on
-        the CIN that defined them, under minimal routing).
+        Each phase needs at least its busiest pair's messages in cycles
+        of link time on that pair's first link (one packet per directed
+        link per cycle), and phases are barrier-serialized, so
+        completion cannot beat the sum over phases — ``num_steps *
+        message_size`` for uniform messages.  The bound is *met with
+        equality* when every phase is contention-free on the fabric (one
+        flow per directed link, e.g. 1-factor steps on the CIN that
+        defined them, under minimal routing).
         """
-        return sum(ph.messages for ph in self.phases)
+        return sum(ph.peak_messages for ph in self.phases)
 
     # -- engine-facing form -------------------------------------------------
     def traffic(self) -> Traffic:
@@ -179,15 +229,18 @@ class Workload:
     def to_dict(self) -> dict:
         return {"name": self.name, "num_switches": self.num_switches,
                 "phases": [{"src": list(ph.src), "dst": list(ph.dst),
-                            "messages": ph.messages}
+                            "messages": (ph.messages if ph.uniform
+                                         else list(ph.messages))}
                            for ph in self.phases]}
 
     @classmethod
     def from_dict(cls, d: Mapping) -> "Workload":
+        def messages(m):
+            return int(m) if isinstance(m, (int, np.integer)) else tuple(m)
         phases = tuple(
             Phase(tuple(int(v) for v in ph["src"]),
                   tuple(int(v) for v in ph["dst"]),
-                  messages=int(ph.get("messages", 1)))
+                  messages=messages(ph.get("messages", 1)))
             for ph in d["phases"])
         return cls(str(d["name"]), int(d["num_switches"]), phases)
 
@@ -463,6 +516,206 @@ def collective_workload(fabric, collective: str = "all_to_all", *,
             f"no {collective!r} workload builder for "
             f"{type(fabric).__name__}; collectives: {known}")
     return builder(fabric, message_size)
+
+
+# ---------------------------------------------------------------------------
+# Expert-parallel dispatch and combine of a mixture-of-experts layer.
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class MoESpec:
+    """An MoE layer's routed experts and its expert-parallel exchange.
+
+    Defaults are DeepSeek-V3's published gate (``n_routed_experts`` 256
+    in ``n_group`` 8 groups, ``topk_group`` 4, ``num_experts_per_tok``
+    8; ``noaux_tc`` over sigmoid scores) and DeepEP's "normal" kernels at
+    its pretraining setting: ``batch_tokens`` 4,096 tokens a rank,
+    ``ranks_per_node`` 8, an FP8 dispatch copy of ``dispatch_bytes``
+    7,392 B (7,168 B of hidden state plus 56 float32 block scales) and a
+    BF16 combine copy of ``combine_bytes`` 14,336 B, cut into packets of
+    ``packet_bytes``.  ``sigma`` is the spread of a per-expert bias drawn
+    anew for every EP group and step: a batch's domain skew, which moves
+    expert load under aux-loss-free balancing (0 = none).
+    """
+    n_routed_experts: int = 256
+    n_group: int = 8
+    topk_group: int = 4
+    num_experts_per_tok: int = 8
+    batch_tokens: int = 4096
+    ranks_per_node: int = 8
+    dispatch_bytes: int = 7392
+    combine_bytes: int = 14336
+    packet_bytes: int = 4096
+    sigma: float = 0.0
+
+    def __post_init__(self):
+        for f in fields(self):
+            v = getattr(self, f.name)
+            if f.name == "sigma":
+                if not v >= 0:
+                    raise ValueError(f"sigma must be >= 0, got {v}")
+                object.__setattr__(self, f.name, float(v))
+            elif int(v) != v or v < 1:
+                raise ValueError(f"{f.name} must be a positive integer, "
+                                 f"got {v!r}")
+            else:
+                object.__setattr__(self, f.name, int(v))
+        per_group, rem = divmod(self.n_routed_experts, self.n_group)
+        if rem or per_group < 2:
+            raise ValueError(
+                f"{self.n_routed_experts} experts do not split into "
+                f"{self.n_group} groups of at least 2 (a group scores "
+                f"by its top-2 experts)")
+        if self.topk_group > self.n_group:
+            raise ValueError(f"topk_group {self.topk_group} > n_group "
+                             f"{self.n_group}")
+        if self.num_experts_per_tok > self.topk_group * per_group:
+            raise ValueError(
+                f"num_experts_per_tok {self.num_experts_per_tok} exceeds "
+                f"the {self.topk_group * per_group} experts of the kept "
+                f"groups")
+
+    @classmethod
+    def coerce(cls, v) -> "MoESpec":
+        """A spec from itself or its dict form; unknown keys raise."""
+        if isinstance(v, cls):
+            return v
+        if not isinstance(v, Mapping):
+            raise ValueError(f"moe params must be a dict, got "
+                             f"{type(v).__name__}")
+        known = {f.name for f in fields(cls)}
+        unknown = sorted(set(v) - known)
+        if unknown:
+            raise ValueError(f"unknown moe params {unknown}; known: "
+                             f"{sorted(known)}")
+        return cls(**dict(v))
+
+    @property
+    def dispatch_packets(self) -> int:
+        """Packets of one token's dispatch copy to one remote node."""
+        return -(-self.dispatch_bytes // self.packet_bytes)
+
+    @property
+    def combine_packets(self) -> int:
+        """Packets of one token's combine copy back from one node."""
+        return -(-self.combine_bytes // self.packet_bytes)
+
+
+def noaux_tc(logits: np.ndarray, moe: MoESpec) -> np.ndarray:
+    """DeepSeek-V3's ``noaux_tc`` top-k: the selected expert ids,
+    ``(tokens, num_experts_per_tok)``, each row a set in no given order.
+
+    Score = sigmoid(logits); a group (``n_routed_experts / n_group``
+    consecutive experts) scores the sum of its two best scores; the
+    ``topk_group`` best groups are kept, and the ``num_experts_per_tok``
+    best experts among theirs are selected.  The correction bias is 0
+    (random weights), so the scores alone select."""
+    t, e = logits.shape
+    g = moe.n_group
+    scores = 1.0 / (1.0 + np.exp(-logits))
+    grouped = scores.reshape(t, g, e // g)
+    group_scores = np.partition(grouped, -2, axis=-1)[..., -2:].sum(-1)
+    kept = np.argpartition(group_scores, -moe.topk_group,
+                           axis=-1)[:, -moe.topk_group:]
+    in_kept = np.zeros((t, g), dtype=bool)
+    np.put_along_axis(in_kept, kept, True, axis=-1)
+    masked = np.where(np.repeat(in_kept, e // g, axis=-1), scores, -np.inf)
+    return np.argpartition(masked, -moe.num_experts_per_tok,
+                           axis=-1)[:, -moe.num_experts_per_tok:]
+
+
+def _gate_copies(moe: MoESpec, groups: int, nodes: int,
+                 seed: int) -> np.ndarray:
+    """``copies[r, s, d]``: tokens of node ``s`` of EP group ``r`` that
+    send one dispatch copy to node ``d != s`` (see
+    :func:`moe_dispatch_workload` for the draw order)."""
+    rng = np.random.default_rng(seed)
+    tokens = moe.batch_tokens * moe.ranks_per_node
+    per_node = moe.n_routed_experts // nodes
+    copies = np.zeros((groups, nodes, nodes), dtype=np.int64)
+    for r in range(groups):
+        bias = rng.normal(0.0, moe.sigma, moe.n_routed_experts)
+        for s in range(nodes):
+            logits = rng.standard_normal(
+                (tokens, moe.n_routed_experts)) + bias
+            hit = np.zeros((tokens, nodes), dtype=bool)
+            np.put_along_axis(hit, noaux_tc(logits, moe) // per_node,
+                              True, axis=-1)
+            hit[:, s] = False
+            copies[r, s] = hit.sum(axis=0)
+    return copies
+
+
+def moe_dispatch_workload(fabric, moe, seed: int) -> Workload:
+    """Expert-parallel dispatch and combine of one MoE layer on every row
+    of a HyperX, drawn from ``seed``.
+
+    Each row (the switches that differ only in the last coordinate) is
+    one EP group whose ``n_group`` switches are its nodes: node ``j``
+    holds experts ``j * E/n .. (j + 1) * E/n - 1`` (E =
+    ``n_routed_experts``, n = ``n_group`` = the last dimension), so
+    node-limited routing sends a token to at most ``topk_group`` nodes.
+    All rows run in lockstep under the fabric-wide phase barrier.
+
+    The draw (a frozen contract: the benchmark holds a copy of it bit
+    for bit): ``rng = np.random.default_rng(seed)``; for each row ``r``
+    in switch order, ``bias = rng.normal(0, sigma, E)``, then for each
+    node ``s`` of the row ``logits = rng.standard_normal((batch_tokens *
+    ranks_per_node, E)) + bias`` in float64, and :func:`noaux_tc`
+    selects each token's experts.  A token of node ``s`` sends one
+    dispatch copy to each distinct node of its experts other than ``s``
+    (DeepEP: one RDMA copy per remote node, forwarded inside the node)
+    and gets one combine copy back from each.
+
+    Phases: one dispatch phase per step of the last dimension's LACIN
+    schedule, pair ``(s, partner)`` carrying ``dispatch_packets x
+    copies(s -> partner)``; then the same steps as combine phases, pair
+    ``(s, partner)`` carrying ``combine_packets x copies(partner -> s)``.
+    Pairs with no copy are dropped.  The ``sweep.gate`` span covers
+    the draw and the count build (inside ``sweep.traffic`` when a sweep
+    draws it); the counters ``moe_copies`` (remote
+    token copies drawn) and ``moe_pair_max_over_mean`` (the busiest
+    pair's copies over the mean pair's, largest over the steps; combine
+    steps carry the same counts transposed) go to the grid's timing
+    dict."""
+    from repro.fabric import HyperXFabric, make_fabric
+    fab = make_fabric(fabric)
+    moe = MoESpec.coerce(moe)
+    if not isinstance(fab, HyperXFabric):
+        raise ValueError(f"the MoE dispatch replay runs on the rows of a "
+                         f"HyperX, not on {type(fab).__name__}")
+    nodes = fab.config.dims[-1]
+    if nodes != moe.n_group:
+        raise ValueError(
+            f"an EP group is one row of {nodes} switches, but the gate "
+            f"limits tokens by n_group={moe.n_group} node groups; use a "
+            f"HyperX whose last dimension is {moe.n_group}")
+    n = fab.config.num_switches
+    groups = n // nodes
+    with span("sweep.gate"):
+        copies = _gate_copies(moe, groups, nodes, seed)
+        sched = fab.schedule()[-1]
+        node = np.arange(nodes)
+        steps = [np.asarray(sched.partners(k)) for k in
+                 range(sched.num_steps)]
+
+        def phase(partner, pairs):
+            r, j = np.nonzero((partner != node) & (pairs > 0))
+            return Phase(tuple((r * nodes + j).tolist()),
+                         tuple((r * nodes + partner[j]).tolist()),
+                         tuple(pairs[r, j].tolist()))
+
+        phases = ([phase(p, moe.dispatch_packets * copies[:, node, p])
+                   for p in steps]
+                  + [phase(p, moe.combine_packets * copies[:, p, node])
+                     for p in steps])
+    count("moe_copies", int(copies.sum()))
+    ratios = [c.max() / c.mean() for c in
+              (copies[:, node, p][:, p != node] for p in steps)
+              if c.size and c.mean() > 0]
+    count("moe_pair_max_over_mean", float(max(ratios, default=0.0)),
+          largest=True)
+    return Workload(f"{fab.name}-moe", n, tuple(phases))
 
 
 # ---------------------------------------------------------------------------

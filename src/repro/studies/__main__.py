@@ -28,6 +28,7 @@ Examples::
     python -m repro.studies specs
     python -m repro.studies run studies_smoke --backend numpy --table
     python -m repro.studies run cin16_saturation --store knees.jsonl
+    python -m repro.studies run moe_dispatch --backend jax
     python -m repro.studies show my_experiment.json
     python -m repro.studies show collective_replay --trace
     python -m repro.studies cache

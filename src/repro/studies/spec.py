@@ -255,6 +255,24 @@ class FabricSpec(_SpecBase):
 #: request-level serving kind (:mod:`repro.workload`).
 _PATTERNS = ("uniform", "permutation", "hotspot", "adversarial", "workload",
              "serving")
+#: The params a ``workload`` pattern knows (see :class:`TrafficSpec`).
+_WORKLOAD_KEYS = frozenset({"collective", "message_size", "workload", "moe"})
+
+
+def _fabric_of(topo, collective: str):
+    """The :class:`repro.fabric.Fabric` a topology was built from, whose
+    schedules a replayed ``collective`` follows."""
+    from repro.fabric import make_fabric
+    meta = getattr(topo, "meta", {}) or {}
+    if "instance" in meta and "n" in meta:
+        return make_fabric(meta["instance"], int(meta["n"]))
+    if meta.get("config") is not None:
+        return make_fabric(meta["config"])
+    raise ValueError(
+        f"workload traffic needs a fabric to derive the {collective!r} "
+        f"schedule from, but topology {topo.name!r} records no "
+        f"construction metadata; pass explicit phases via "
+        f"params['workload']")
 
 
 @dataclass(frozen=True, eq=True)
@@ -279,10 +297,17 @@ class TrafficSpec(_SpecBase):
 
     * ``{"collective": "all_to_all" | "all_reduce", "message_size": m}``
       — the workload is derived from the experiment's *own fabric*
-      (its LACIN schedules), so the spec stays fully declarative; or
+      (its LACIN schedules), so the spec stays fully declarative;
     * ``{"workload": {...}}`` — an explicit
       :meth:`repro.sim.workloads.Workload.to_dict` payload, replayed
-      verbatim (still serializable).
+      verbatim (still serializable); or
+    * ``{"moe": {...}}``, alone — a mixture-of-experts layer's
+      expert-parallel dispatch and combine on the rows of the
+      experiment's HyperX (:func:`repro.sim.workloads.moe_dispatch_workload`,
+      params :class:`repro.sim.workloads.MoESpec`), drawn anew from each
+      grid point's seed.
+
+    Other keys are refused.
 
     **Serving streams** (``serving``): open-loop *request* arrivals from
     an :class:`repro.workload.ArrivalSpec` — ``params`` is
@@ -317,6 +342,8 @@ class TrafficSpec(_SpecBase):
                 return inner
             return lambda load, seed: inner(load)
         if self.pattern == "workload":
+            if "moe" in self.params:
+                return self._moe_factory(topo)
             tr = self._resolve_workload(topo).traffic()
             return lambda load, seed: tr
         if self.pattern == "serving":
@@ -369,12 +396,32 @@ class TrafficSpec(_SpecBase):
                        **kw)
         return make
 
+    def _workload_params(self) -> dict:
+        kw = dict(self.params)
+        unknown = sorted(set(kw) - _WORKLOAD_KEYS)
+        if unknown:
+            raise ValueError(f"unknown workload traffic params {unknown}; "
+                             f"known: {sorted(_WORKLOAD_KEYS)}")
+        return kw
+
+    def _moe_factory(self, topo) -> Callable:
+        """``(load, seed) -> Traffic`` of the MoE exchange, drawn from
+        each grid point's seed."""
+        from repro.sim.workloads import MoESpec, moe_dispatch_workload
+        kw = self._workload_params()
+        if set(kw) != {"moe"}:
+            raise ValueError(f"moe params stand alone; got {sorted(kw)}")
+        moe = MoESpec.coerce(kw["moe"])
+        fab = _fabric_of(topo, "moe")
+        return lambda load, seed: moe_dispatch_workload(
+            fab, moe, seed).traffic()
+
     def _resolve_workload(self, topo):
         """The :class:`repro.sim.workloads.Workload` this spec replays on
         ``topo`` — explicit phases if given, else the named collective's
         step sequence on the fabric the topology was built from."""
         from repro.sim.workloads import Workload, collective_workload
-        kw = dict(self.params)
+        kw = self._workload_params()
         if "workload" in kw:
             w = Workload.from_dict(kw["workload"])
             if w.num_switches != topo.num_switches:
@@ -386,21 +433,9 @@ class TrafficSpec(_SpecBase):
                     f"switches but the experiment's fabric "
                     f"{topo.name!r} has {topo.num_switches}")
             return w
-        meta = getattr(topo, "meta", {}) or {}
-        if "instance" in meta and "n" in meta:
-            from repro.fabric import make_fabric
-            fab = make_fabric(meta["instance"], int(meta["n"]))
-        elif meta.get("config") is not None:
-            from repro.fabric import make_fabric
-            fab = make_fabric(meta["config"])
-        else:
-            raise ValueError(
-                f"workload traffic needs a fabric to derive the "
-                f"{kw.get('collective', 'all_to_all')!r} schedule from, "
-                f"but topology {topo.name!r} records no construction "
-                f"metadata; pass explicit phases via params['workload']")
+        collective = str(kw.get("collective", "all_to_all"))
         return collective_workload(
-            fab, str(kw.get("collective", "all_to_all")),
+            _fabric_of(topo, collective), collective,
             message_size=int(kw.get("message_size", 1)))
 
     @property
@@ -409,6 +444,8 @@ class TrafficSpec(_SpecBase):
             wl = self.params.get("workload")
             if isinstance(wl, Mapping):
                 return f"replay-{wl.get('name', 'workload')}"
+            if "moe" in self.params:
+                return "replay-moe"
             return f"replay-{self.params.get('collective', 'all_to_all')}"
         if self.pattern == "serving":
             arrival = self.params.get("arrival")

@@ -148,6 +148,17 @@ def test_spans_nest_into_the_open_record():
     assert telemetry.recorded_spans() == {}
 
 
+def test_counters_add_or_keep_the_largest_in_the_open_record():
+    with span_record() as rec:
+        telemetry.count("copies", 3)
+        telemetry.count("copies", 3)
+        telemetry.count("ratio", 1.5, largest=True)
+        telemetry.count("ratio", 1.25, largest=True)
+    assert rec == {"copies": 6, "ratio": 1.5}
+    telemetry.count("copies", 1)              # no open record: dropped
+    assert telemetry.recorded_spans() == {}
+
+
 def _study_spec(seed):
     from repro.studies import ExperimentSpec
     return ExperimentSpec(

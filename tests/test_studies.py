@@ -540,3 +540,73 @@ def test_flush_interval_mid_write_crash_repairs_and_resumes(tmp_path):
                 backend="numpy").run()
     assert out.restored == 4 and out.executed == 2
     assert len(JsonlStore(store_path).load()) == 6
+
+
+@pytest.mark.parametrize("params", [
+    {"colective": "all_to_all"},
+    {"collective": "all_to_all", "messages": 2},
+    {"moe": {"batch_tokens": 2}, "message_size": 2},
+    {"collective": "all_to_all", "moe": {"batch_tokens": 2}},
+    {"moe": {"batch_tokens": 2, "experts": 64}},
+])
+def test_unknown_workload_params_raise(params):
+    spec = ExperimentSpec(
+        fabric=FabricSpec("hyperx", {"dims": [2, 8], "terminals": 1}),
+        traffic=TrafficSpec("workload", params),
+        routing=RoutingSpec("minimal"), sweep=SweepSpec(loads=(0.0,)))
+    with pytest.raises(ValueError, match="unknown|moe params"):
+        Study(spec, backend="numpy").run()
+
+
+def _moe_spec(seeds):
+    [exp] = studies.load_specs(studies.bundled_spec_path("moe_dispatch"))
+    return exp.with_sweep(seeds=tuple(seeds))
+
+
+def test_moe_study_draws_from_each_point_seed():
+    """The bundled ``moe_dispatch`` spec through the compiled sweep: each
+    grid seed redraws the gate, the same seed the same packets, and
+    every point meets its own contention-free bound."""
+    spec = _moe_spec([0, 1])
+    assert ExperimentSpec.from_json(spec.to_json()) == spec
+    assert spec.traffic.label == "replay-moe"
+    out = Study(spec, backend="jax").run()
+    a, b = out.results
+    assert a.packets_generated != b.packets_generated
+    [c] = Study(_moe_spec([0]), backend="jax").run().results
+    assert (a.packets_generated, a.ideal_cycles, a.phase_cycles) == \
+        (c.packets_generated, c.ideal_cycles, c.phase_cycles)
+    for r in out.results:
+        assert r.completion_cycles == r.ideal_cycles
+    timing = out.results[0].stats.timing
+    assert timing["moe_copies"] > 0 and "sweep.gate_s" in timing
+    assert timing["moe_pair_max_over_mean"] >= 1.0
+    numpy = Study(_moe_spec([1]), backend="numpy").run().results[0]
+    assert (numpy.packets_generated, numpy.phase_cycles) == \
+        (b.packets_generated, b.phase_cycles)
+
+
+def test_replay_points_hold_each_point_to_its_bound():
+    """The worst ratio over the grid, with that point's own bound (not
+    the first point's)."""
+    from types import SimpleNamespace
+    from repro.studies.runner import StudyResult
+    spec = _moe_spec([0, 1])
+
+    def rec(completion, ideal):
+        return SimpleNamespace(experiment=spec.name,
+                               completion_cycles=completion,
+                               ideal_cycles=ideal)
+
+    res = StudyResult([spec], [rec(100, 100), rec(90, 60)], 2, 0, "jax")
+    assert res.replay_points()[spec.name] == {
+        "measured": 90, "ideal": 60, "ratio": 1.5}
+
+
+def test_moe_cli_example(tmp_path, capsys):
+    from repro.studies.__main__ import main as cli
+    store = tmp_path / "moe.jsonl"
+    assert cli(["run", "moe_dispatch", "--backend", "numpy",
+                "--store", str(store)]) == 0
+    out = capsys.readouterr().out
+    assert "collective replay" in out and "ratio=1.0" in out

@@ -83,6 +83,39 @@ def test_xengine_dragonfly_1040_grid_compiles_and_fits(one_chip,
     assert 0 < used < V5E_HBM_BYTES, mem
 
 
+def test_xengine_moe_replay_compiles_and_fits(one_chip, monkeypatch):
+    """The drained replay of DeepSeek-V3's EP dispatch and combine on the
+    12 x 8 HyperX (128 tokens a rank, 7 ranks a node): ~1.8 M packets
+    in 14 uneven phases, one grid point, the benchmark cell's program."""
+    from repro.core.hyperx import HyperXConfig
+    from repro.fabric import make_fabric
+    from repro.sim import xengine
+    from repro.sim.workloads import MoESpec, moe_dispatch_workload
+    fab = make_fabric(HyperXConfig(dims=(12, 8), terminals=7,
+                                   instance="circle"))
+    moe = MoESpec(batch_tokens=128, ranks_per_node=7, sigma=0.5)
+    captured = {}
+
+    def capture(fn, static_arg, *args, **kw):
+        captured.update(fn=fn, spec=static_arg, args=args)
+        raise _Captured
+
+    monkeypatch.setattr(xengine, "timed_compiled", capture)
+    with pytest.raises(_Captured):
+        xengine.sweep(fab.sim_topology(), "minimal",
+                      lambda load, seed: moe_dispatch_workload(
+                          fab, moe, seed).traffic(),
+                      [0.0], seeds=(5,), terminals=7)
+    spec = captured["spec"]
+    assert (spec.drain, spec.num_phases) == (True, 14)
+    assert captured["args"][1]["src"].shape == (2_097_152,)
+    compiled = xengine._run_flat.lower(
+        spec, *_shapes(captured["args"], one_chip)).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < V5E_HBM_BYTES, mem
+
+
 def test_flash_attention_compiles_for_tpu(one_chip):
     from repro.kernels.ops import flash_attention
     qkv = jax.ShapeDtypeStruct((1, 2048, 8, 128), jnp.bfloat16,

@@ -9,6 +9,7 @@ ExperimentSpec JSON, studies/CLI integration, and the one-shot traffic
 ``terminals`` recording fix.
 """
 import json
+import math
 
 import numpy as np
 import pytest
@@ -325,3 +326,148 @@ def test_one_shot_permutation_records_terminals():
     with pytest.raises(ValueError, match="disagrees"):
         sim.xengine.simulate_jax(sim.cin_topology("xor", 4),
                                  sim.MinimalPolicy(), tr, terminals=4)
+
+
+# ---------------------------------------------------------------------------
+# Per-pair phases and the MoE expert-parallel exchange.
+# ---------------------------------------------------------------------------
+
+def test_uniform_phases_keep_the_scalar_bound_and_packets():
+    """An int ``messages`` is the old path: same packets, same bound; the
+    same counts written per pair give the same packets too."""
+    w = collective_workload(make_fabric(HyperXConfig(dims=(4, 4),
+                                                     terminals=2)),
+                            "all_to_all", message_size=3)
+    assert all(ph.uniform for ph in w.phases)
+    assert w.ideal_cycles == sum(ph.messages for ph in w.phases) == 6 * 3
+    per_pair = Workload(w.name, w.num_switches, tuple(
+        Phase(ph.src, ph.dst, tuple([ph.messages] * len(ph.src)))
+        for ph in w.phases))
+    a, b = w.traffic(), per_pair.traffic()
+    for field in ("src", "dst", "gen"):
+        assert np.array_equal(getattr(a, field), getattr(b, field))
+    assert per_pair.ideal_cycles == w.ideal_cycles
+    assert np.array_equal(per_pair.phase_cum(), w.phase_cum())
+
+
+def test_per_pair_phase_counts_and_round_trip():
+    w = Workload("uneven", 4, (Phase((0, 1, 2), (1, 2, 3), (3, 1, 5)),
+                               Phase((3,), (0,), 2),
+                               Phase((), (), ())))
+    assert [ph.num_packets for ph in w.phases] == [9, 2, 0]
+    assert w.ideal_cycles == 5 + 2 + 0
+    assert w.phase_cum().tolist() == [9, 11, 11]
+    tr = w.traffic()
+    assert tr.src.tolist() == [0] * 3 + [1] + [2] * 5 + [3] * 2
+    assert tr.gen.tolist() == [0] * 9 + [1] * 2
+    d = w.to_dict()
+    assert d["phases"][0]["messages"] == [3, 1, 5]
+    assert d["phases"][1]["messages"] == 2
+    assert Workload.from_dict(json.loads(json.dumps(d))) == w
+    spec = _replay_spec({"workload": d})
+    assert studies.ExperimentSpec.from_json(spec.to_json()) == spec
+
+
+def test_per_pair_phase_validation():
+    with pytest.raises(ValueError, match="one count per pair"):
+        Phase((0, 1), (1, 0), (1,))
+    with pytest.raises(ValueError, match=">= 1"):
+        Phase((0, 1), (1, 0), (1, 0))
+
+
+def _noaux_tc_per_token(logits, n_group, topk_group, top_k):
+    """DeepSeek-V3's noaux_tc for one token, as the model card describes
+    it: sigmoid scores; each of ``n_group`` groups of consecutive experts
+    scores the sum of its top-2 scores; keep the ``topk_group`` best
+    groups; take the ``top_k`` best experts among the kept groups."""
+    scores = [1.0 / (1.0 + math.exp(-float(x))) for x in logits]
+    size = len(scores) // n_group
+    group_score = []
+    for g in range(n_group):
+        best = sorted(scores[g * size:(g + 1) * size], reverse=True)
+        group_score.append(best[0] + best[1])
+    kept = sorted(range(n_group), key=lambda g: -group_score[g])[:topk_group]
+    candidates = [e for g in kept for e in range(g * size, (g + 1) * size)]
+    return set(sorted(candidates, key=lambda e: -scores[e])[:top_k])
+
+
+@pytest.mark.parametrize("seed", [0, 17, 2**31 + 3])
+def test_gate_matches_per_token_noaux_tc_at_published_widths(seed):
+    """The vectorised draw against per-token loops: the same selected
+    experts and the same copies per (node, node) pair, at DeepSeek-V3's
+    widths (256 experts, 8 groups, top-4 groups, top-8 experts) on two
+    EP groups of 8 nodes x 24 tokens (384 tokens)."""
+    moe = workloads.MoESpec(batch_tokens=8, ranks_per_node=3, sigma=0.5)
+    groups, nodes = 2, 8
+    tokens = moe.batch_tokens * moe.ranks_per_node
+    rng = np.random.default_rng(seed)
+    want = np.zeros((groups, nodes, nodes), dtype=np.int64)
+    for r in range(groups):
+        bias = rng.normal(0.0, moe.sigma, moe.n_routed_experts)
+        for s in range(nodes):
+            logits = rng.standard_normal((tokens,
+                                          moe.n_routed_experts)) + bias
+            got = workloads.noaux_tc(logits, moe)
+            for t in range(tokens):
+                chosen = _noaux_tc_per_token(logits[t], 8, 4, 8)
+                assert set(got[t].tolist()) == chosen
+                # node-limited: at most topk_group nodes a token
+                assert len({e // 32 for e in chosen}) <= 4
+                for d in {e // 32 for e in chosen} - {s}:
+                    want[r, s, d] += 1
+    assert np.array_equal(
+        workloads._gate_copies(moe, groups, nodes, seed), want)
+
+
+def test_moe_workload_follows_the_row_schedule():
+    """Dispatch phase k is the last dimension's Circle step k inside every
+    row, 2 packets a copy; combine phase k the same step carrying 4
+    packets a copy the other way; per-pair counts follow the draw."""
+    fab = make_fabric(HyperXConfig(dims=(3, 8), terminals=2,
+                                   instance="circle"))
+    moe = workloads.MoESpec(batch_tokens=16, ranks_per_node=2)
+    w = workloads.moe_dispatch_workload(fab, moe, seed=5)
+    copies = workloads._gate_copies(moe, 3, 8, 5)
+    sched = fab.schedule()[-1]
+    assert w.num_phases == 2 * sched.num_steps == 14
+    for k in range(7):
+        row = sched.partners(k)
+        disp, comb = w.phases[k], w.phases[7 + k]
+        for ph, per_copy, transpose in ((disp, 2, False), (comb, 4, True)):
+            for s, d, m in zip(ph.src, ph.dst, ph.messages):
+                assert s // 8 == d // 8 and d % 8 == row[s % 8]
+                a, b = (d % 8, s % 8) if transpose else (s % 8, d % 8)
+                assert m == per_copy * copies[s // 8, a, b]
+    assert w.num_packets == 6 * copies.sum()
+    assert w.ideal_cycles == sum(ph.peak_messages for ph in w.phases)
+    again = workloads.moe_dispatch_workload(fab, moe, seed=5)
+    assert again == w
+    assert workloads.moe_dispatch_workload(fab, moe, seed=6) != w
+
+
+def test_moe_workload_refuses_what_it_cannot_place():
+    moe = workloads.MoESpec(batch_tokens=2, ranks_per_node=1)
+    with pytest.raises(ValueError, match="HyperX"):
+        workloads.moe_dispatch_workload(make_fabric("xor", 8), moe, 0)
+    with pytest.raises(ValueError, match="last dimension is 8"):
+        workloads.moe_dispatch_workload(
+            make_fabric(HyperXConfig(dims=(8, 4), terminals=1)), moe, 0)
+    with pytest.raises(ValueError, match="unknown moe params"):
+        workloads.MoESpec.coerce({"experts": 256})
+    with pytest.raises(ValueError, match="topk_group"):
+        workloads.MoESpec(topk_group=9)
+
+
+@pytest.mark.parametrize("chunk", range(4))
+def test_moe_full_size_packet_counts_share_one_bucket(chunk):
+    """The benchmark's deployment (12 x 8 HyperX, 128 tokens a rank, 7
+    ranks a node, sigma 0.5): the packet counts of 64 seeds, 16 per case,
+    fall in one ``_bucket_count`` rung, so every grid reuses one
+    compiled program.  Host only: no simulation."""
+    from repro.sim.xengine import _bucket_count
+    fab = make_fabric(HyperXConfig(dims=(12, 8), terminals=7,
+                                   instance="circle"))
+    moe = workloads.MoESpec(batch_tokens=128, ranks_per_node=7, sigma=0.5)
+    rungs = {_bucket_count(6 * workloads._gate_copies(moe, 12, 8, seed).sum())
+             for seed in range(16 * chunk, 16 * chunk + 16)}
+    assert rungs == {_bucket_count(1_789_000)} == {2_097_152}
